@@ -28,9 +28,11 @@ def time_call(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:
 
 
 def run_with_devices(code: str, devices: int, timeout: int = 900) -> dict:
-    """Run a snippet in a child with N host devices; it must print one JSON
-    line starting with RESULT."""
-    env = dict(os.environ,
+    """Run a snippet in a child with N host (CPU) devices; it must print one
+    JSON line starting with RESULT.  The child is pinned to the CPU: forced
+    host devices exist only there, and a chip belongs to one process —
+    which may be the parent."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=str(REPO / "src"))
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

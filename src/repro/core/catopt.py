@@ -317,7 +317,7 @@ def optimize_islands(problem: CatBondProblem, cfg: GAConfig, key,
     device is an island with cfg.pop_size individuals; ring migration every
     cfg.migrate_every generations over ``lax.ppermute``.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     devices = mesh.devices.reshape(-1)
     n_islands = int(devices.size)
     island_mesh = Mesh(devices, ("island",))
@@ -363,7 +363,7 @@ def optimize_islands(problem: CatBondProblem, cfg: GAConfig, key,
                    in_specs=P("island", None),
                    out_specs=(P("island", None), P("island"),
                               P("island", None)),
-                   check_rep=False)
+                   check_vma=False)
     with island_mesh:
         w_all, fit_all, hist_all = jax.jit(fn)(keys)
     best_island = int(np.argmin(np.asarray(fit_all)))

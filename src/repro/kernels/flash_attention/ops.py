@@ -2,16 +2,14 @@
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.flash_attention import ref as _ref
 
-_USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -20,8 +18,7 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               interpret: Optional[bool] = None,
               block_q: int = 256, block_k: int = 512) -> jnp.ndarray:
     """q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D) -> (B, Sq, H, D)."""
-    use_pallas = _USE_PALLAS if use_pallas is None else use_pallas
-    interpret = _INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if not use_pallas:
         return _ref.attention(q, k, v, causal=causal, window=window,
                               softcap=softcap)
@@ -95,7 +92,7 @@ def attention_trainable(q, k, v, *, causal: bool = True, window: int = 0,
                         softcap: float = 0.0, interpret: Optional[bool] = None,
                         block_q: int = 256, block_k: int = 512):
     """(B, S, H, D) API over the custom-vjp kernel pair."""
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = resolve(True, interpret)[1]
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)

@@ -1,5 +1,13 @@
 """Pallas TPU kernel: paged-KV decode attention (block-table walk).
 
+Layout: pages are kv-head-major, ``(num_blocks, Hkv, BS, D)`` per layer,
+so one block is one whole ``(BS, D)`` page of one kv head.  Every block's
+last two dims equal the array's, which is what the TPU lowering demands
+of a block that is not a multiple of the (8, 128) tile.  int8 pools
+carry fp32 per-row scale pools ``(num_blocks, Hkv, BS)``; the kernel
+reads a page's scales for all heads as one ``(Hkv, BS)`` block and picks
+its head's row (turned into a column to scale the page's rows).
+
 Grid: (B, Hkv, max_live_blocks) with dimension_semantics (parallel,
 parallel, arbitrary) — the innermost axis walks each request's *logical*
 blocks in order, carrying the online-softmax state (m, l, acc) in VMEM
@@ -26,7 +34,10 @@ writes them into the visited page *in the kernel prologue* (the pools are
 input/output aliased; every visited page is copied through and written
 back).  Decode touches the cache once per layer — no separate
 scatter-then-gather dispatch.  Padded rows (position -1) are simply not
-written; the null block stays garbage by design.
+written; the null block stays garbage by design.  With int8 pools the
+fresh rows' scales are scattered into the (small) scale pools before the
+launch: a scale block spans every kv head of its page, so the per-head
+grid steps could not write it back without racing each other.
 
 Windows: blocks wholly outside every live row's sliding window are
 skipped by the same ``pl.when`` predicate (window arrives as a traced
@@ -41,32 +52,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e9
 
 
+def _column(row):
+    """(1, n) -> (n, 1) through a masked lane reduction — exact, since
+    each output sums one entry and zeros."""
+    n = row.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.where(eye, row, 0.0).sum(axis=1, keepdims=True)
+
+
 def _paged_kernel(tab_ref, pos_ref, maxp_ref, minp_ref, win_ref,  # scalars
-                  *refs, fuse: bool, quant: bool, S: int, G: int, BS: int,
-                  nb: int, softcap: float, scale: float):
-    if fuse and quant:
-        (qpos_ref, q_ref, kn_ref, vn_ref, ksn_ref, vsn_ref,
-         kp_ref, vp_ref, ksp_ref, vsp_ref,
-         o_ref, kpo_ref, vpo_ref, kspo_ref, vspo_ref,
-         m_ref, l_ref, acc_ref) = refs
-    elif fuse:
-        (qpos_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
-         o_ref, kpo_ref, vpo_ref, m_ref, l_ref, acc_ref) = refs
-    elif quant:
-        (qpos_ref, q_ref, kp_ref, vp_ref, ksp_ref, vsp_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-        kpo_ref, vpo_ref = kp_ref, vp_ref
-        kspo_ref, vspo_ref = ksp_ref, vsp_ref
-    else:
-        (qpos_ref, q_ref, kp_ref, vp_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-        kpo_ref, vpo_ref = kp_ref, vp_ref
+                  *refs, fuse: bool, quant: bool, S: int, BS: int, nb: int,
+                  softcap: float, scale: float):
+    refs = list(refs)
+    qpos_ref, q_ref = refs.pop(0), refs.pop(0)
+    kn_ref, vn_ref = (refs.pop(0), refs.pop(0)) if fuse else (None, None)
+    kp_ref, vp_ref = refs.pop(0), refs.pop(0)
+    ksp_ref, vsp_ref = (refs.pop(0), refs.pop(0)) if quant else (None, None)
+    o_ref = refs.pop(0)
+    kpo_ref, vpo_ref = (refs.pop(0), refs.pop(0)) if fuse else (None, None)
+    m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     maxp = maxp_ref[b]
     last = jnp.maximum(maxp, 0) // BS        # row's last live logical block
@@ -77,31 +87,27 @@ def _paged_kernel(tab_ref, pos_ref, maxp_ref, minp_ref, win_ref,  # scalars
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # pages are handled in 32-bit (f32 for fp pools, int32 for int8 ones):
+    # the conversions are exact and keep every select and store on the
+    # TPU's native 32-bit layout whatever the pool dtype
+    wide = jnp.int32 if quant else jnp.float32
+    k = kp_ref[0, 0].astype(wide)                            # (BS, D)
+    v = vp_ref[0, 0].astype(wide)
     if fuse:
         # Copy the visited page through and scatter this step's fresh rows
         # into it.  Steps clamped past ``last`` alias the last live page and
         # their input block is NOT refetched (same index map output), so the
         # scatter must be re-applied there — hence the clamp on jl.
-        kpo_ref[...] = kp_ref[...]
-        vpo_ref[...] = vp_ref[...]
-        if quant:
-            kspo_ref[...] = ksp_ref[...]
-            vspo_ref[...] = vsp_ref[...]
         jl = jnp.minimum(j, last)
+        rows = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
         for si in range(S):
             p = pos_ref[b, si]
-
-            @pl.when((p >= 0) & (p // BS == jl))
-            def _scatter(si=si, p=p):
-                off = p % BS
-                kpo_ref[0, pl.ds(off, 1), 0, :] = kn_ref[0, si:si + 1, 0, :]
-                vpo_ref[0, pl.ds(off, 1), 0, :] = vn_ref[0, si:si + 1, 0, :]
-                if quant:
-                    # fresh rows arrive pre-quantized (ref.quantize_rows in
-                    # the wrapper — bit-identical to the reference scatter);
-                    # their per-row scales land in the parallel scale page
-                    kspo_ref[0, pl.ds(off, 1), 0] = ksn_ref[0, si:si + 1, 0]
-                    vspo_ref[0, pl.ds(off, 1), 0] = vsn_ref[0, si:si + 1, 0]
+            off = jnp.where((p >= 0) & (p // BS == jl), p % BS, -1)
+            hit = rows == off
+            k = jnp.where(hit, kn_ref[0, 0, si:si + 1, :].astype(wide), k)
+            v = jnp.where(hit, vn_ref[0, 0, si:si + 1, :].astype(wide), v)
+        kpo_ref[0, 0] = k.astype(kpo_ref.dtype)
+        vpo_ref[0, 0] = v.astype(vpo_ref.dtype)
 
     win = win_ref[0]
     # run only live blocks that overlap some row's (causal, window) band
@@ -110,47 +116,43 @@ def _paged_kernel(tab_ref, pos_ref, maxp_ref, minp_ref, win_ref,  # scalars
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (SG, D)
+        kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
         if quant:
-            # fused dequant: int8 page rows * their fp32 per-row scales
-            k = kpo_ref[0, :, 0, :].astype(jnp.float32) \
-                * kspo_ref[0, :, 0][:, None]                 # (BS, D)
-            v = vpo_ref[0, :, 0, :].astype(jnp.float32) \
-                * vspo_ref[0, :, 0][:, None]
-        else:
-            k = kpo_ref[0, :, 0, :]                          # (BS, D)
-            v = vpo_ref[0, :, 0, :]
+            # fused dequant: int8 rows times their fp32 per-row scales
+            kf = kf * _column(ksp_ref[0, pl.ds(h, 1), :])
+            vf = vf * _column(vsp_ref[0, pl.ds(h, 1), :])
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (SG, D)
         s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            q, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)              # (SG, BS)
         if softcap > 0.0:
             s = jnp.tanh(s / softcap) * softcap
         SG = q.shape[0]
-        q_pos = qpos_ref[0].reshape(SG, 1)
+        q_pos = qpos_ref[0]                                  # (SG, 1)
         k_pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (SG, BS), 1)
         valid = (k_pos <= q_pos) & ((q_pos - k_pos) < win) & (q_pos >= 0)
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                  # (SG, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == nb - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _call(q, k_new, v_new, k_pool, v_pool, block_tables, positions, *,
           window, softcap: float, max_live_blocks: int, interpret: bool,
           fuse: bool, k_scale=None, v_scale=None):
     B, S, H, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
+    NB, Hkv, BS, _ = k_pool.shape
     G = H // Hkv
     SG = S * G
     MB = block_tables.shape[1]
@@ -166,96 +168,74 @@ def _call(q, k_new, v_new, k_pool, v_pool, block_tables, positions, *,
     minp = jnp.min(jnp.where(positions >= 0, positions, jnp.int32(2 ** 30)),
                    axis=1)                                          # (B,)
     win = jnp.asarray(window, jnp.int32).reshape(1)
-    qpos = jnp.repeat(positions, G, axis=1)                         # (B, SG)
+    qpos = jnp.repeat(positions, G, axis=1)[..., None]            # (B, SG, 1)
 
     def page_map(b, h, j, tab, pos, mx, mn, w):
         live_last = jnp.maximum(mx[b], 0) // BS
-        return (tab[b, jnp.minimum(j, live_last)], 0, h, 0)
+        return (tab[b, jnp.minimum(j, live_last)], h, 0, 0)
 
     def scale_page_map(b, h, j, tab, pos, mx, mn, w):
         live_last = jnp.maximum(mx[b], 0) // BS
-        return (tab[b, jnp.minimum(j, live_last)], 0, h)
+        return (tab[b, jnp.minimum(j, live_last)], 0, 0)
 
-    def row_map(b, h, j, *_):
-        return (b, 0)
-
-    def q_map(b, h, j, *_):
+    def head_map(b, h, j, *_):
         return (b, h, 0, 0)
 
-    def new_map(b, h, j, *_):
-        return (b, 0, h, 0)
-
-    def scale_new_map(b, h, j, *_):
-        return (b, 0, h)
-
-    page_spec = pl.BlockSpec((1, BS, 1, D), page_map)
-    scale_page_spec = pl.BlockSpec((1, BS, 1), scale_page_map)
-    in_specs = [pl.BlockSpec((1, SG), row_map),
-                pl.BlockSpec((1, 1, SG, D), q_map)]
+    page_spec = pl.BlockSpec((1, 1, BS, D), page_map)
+    in_specs = [pl.BlockSpec((1, SG, 1), lambda b, h, j, *_: (b, 0, 0)),
+                pl.BlockSpec((1, 1, SG, D), head_map)]
     ins = [qpos, qf]
+    if quant and fuse:
+        # quantize once out here with the shared reference recipe, so the
+        # int8 rows the prologue scatters — and the scales scattered here —
+        # are bit-identical to ref.write_kv's
+        from repro.kernels.paged_attention import ref as _ref
+        k_new, ks = _ref.quantize_rows(k_new)
+        v_new, vs = _ref.quantize_rows(v_new)
+        k_scale = _ref.scatter_rows(k_scale, ks, positions, tables)
+        v_scale = _ref.scatter_rows(v_scale, vs, positions, tables)
     if fuse:
-        in_specs += [pl.BlockSpec((1, S, 1, D), new_map),
-                     pl.BlockSpec((1, S, 1, D), new_map)]
-        if quant:
-            # quantize once out here with the shared reference recipe, so
-            # the int8 rows (and scales) the prologue scatters are
-            # bit-identical to ref.write_kv's
-            from repro.kernels.paged_attention import ref as _ref
-            kq, ks = _ref.quantize_rows(k_new)
-            vq, vs = _ref.quantize_rows(v_new)
-            ins += [kq, vq]
-            in_specs += [pl.BlockSpec((1, S, 1), scale_new_map),
-                         pl.BlockSpec((1, S, 1), scale_new_map)]
-            ins += [ks.astype(k_scale.dtype), vs.astype(v_scale.dtype)]
-        else:
-            ins += [k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype)]
+        # fresh rows kv-head-major like the pages: one (S, D) block per head
+        in_specs += [pl.BlockSpec((1, 1, S, D), head_map)] * 2
+        ins += [k_new.astype(k_pool.dtype).transpose(0, 2, 1, 3),
+                v_new.astype(v_pool.dtype).transpose(0, 2, 1, 3)]
     in_specs += [page_spec, page_spec]
     ins += [k_pool, v_pool]
     if quant:
-        in_specs += [scale_page_spec, scale_page_spec]
+        in_specs += [pl.BlockSpec((1, Hkv, BS), scale_page_map)] * 2
         ins += [k_scale, v_scale]
 
-    out_specs = [pl.BlockSpec((1, 1, SG, D), q_map)]
+    out_specs = [pl.BlockSpec((1, 1, SG, D), head_map)]
     out_shape = [jax.ShapeDtypeStruct((B, Hkv, SG, D), q.dtype)]
+    aliases = {}
     if fuse:
         out_specs += [page_spec, page_spec]
         out_shape += [jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                       jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
-        if quant:
-            out_specs += [scale_page_spec, scale_page_spec]
-            out_shape += [jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-                          jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype)]
         # pools are updated in place: unvisited pages must persist, so the
-        # pool inputs MUST alias the pool outputs.  Indices count the scalar
-        # prefetch operands: 5 scalars + [qpos, q, k_new, v_new] puts the
-        # pools at operands 9, 10 (outputs 1, 2); with quantization the two
-        # fresh-scale operands shift the pools to 11, 12 and add the scale
-        # pools at 13, 14 (outputs 3, 4).
-        if quant:
-            aliases = {11: 1, 12: 2, 13: 3, 14: 4}
-        else:
-            aliases = {9: 1, 10: 2}
-    else:
-        aliases = {}
+        # pool inputs MUST alias the pool outputs.  Operand indices count
+        # the 5 scalar-prefetch operands: [qpos, q, k_new, v_new] puts the
+        # pools at operands 9, 10 (outputs 1, 2).
+        aliases = {9: 1, 10: 2}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B, Hkv, nb),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((SG,), jnp.float32),
-                        pltpu.VMEM((SG,), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((SG, 1), jnp.float32),
+                        pltpu.VMEM((SG, 1), jnp.float32),
                         pltpu.VMEM((SG, D), jnp.float32)],
     )
     kernel = functools.partial(_paged_kernel, fuse=fuse, quant=quant, S=S,
-                               G=G, BS=BS, nb=nb, softcap=softcap,
+                               BS=BS, nb=nb, softcap=softcap,
                                scale=D ** -0.5)
     res = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, positions, maxp, minp, win, *ins)
@@ -263,7 +243,7 @@ def _call(q, k_new, v_new, k_pool, v_pool, block_tables, positions, *,
     out = res[0].reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4) \
                 .reshape(B, S, H, D)
     if fuse and quant:
-        return out, res[1], res[2], res[3], res[4]
+        return out, res[1], res[2], k_scale, v_scale
     if fuse:
         return out, res[1], res[2]
     return out
@@ -304,7 +284,7 @@ def copy_page_pallas(pool, src, dst, *, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         # operand 0 is the scalar prefetch, so the pool is operand 1
         input_output_aliases={1: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx, pool)[0]
@@ -318,7 +298,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, positions, *,
                            v_scale=None):
     """Read-only block-table walk.  q: (B, S, H, D) -> (B, S, H, D).
 
-    With ``k_scale``/``v_scale`` ((NB, BS, Hkv) fp32) the pools are int8
+    With ``k_scale``/``v_scale`` ((NB, Hkv, BS) fp32) the pools are int8
     and the walk dequantizes each visited page in the kernel body.
     """
     return _call(q, None, None, k_pool, v_pool, block_tables, positions,
@@ -336,13 +316,15 @@ def paged_attention_update_pallas(q, k_new, v_new, k_pool, v_pool,
                                   v_scale=None):
     """Fused scatter + block-table walk.
 
-    Writes this step's fresh K/V rows (B, S, Hkv, D) into their pages in
-    the kernel prologue, then attends over the updated pages.  Returns
+    Writes this step's fresh K/V rows (B, S, Hkv, D) into their
+    (NB, Hkv, BS, D) pages in the kernel prologue, then attends over the
+    updated pages.  Returns
     (out (B, S, H, D), k_pool, v_pool).
 
     With ``k_scale``/``v_scale`` the pools are int8: the fresh rows are
-    quantized with the shared reference recipe before the launch, the
-    prologue scatters int8 rows + their per-row scales, the walk
+    quantized with the shared reference recipe before the launch, their
+    per-row scales scattered into the scale pools, the prologue scatters
+    the int8 rows, the walk
     dequantizes in fp32, and the return grows to
     (out, k_pool, v_pool, k_scale, v_scale).
     """

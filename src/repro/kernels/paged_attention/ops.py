@@ -1,8 +1,11 @@
 """Op boundary for paged-KV decode attention with a ``use_pallas`` switch.
 
-Mirrors ``flash_attention/ops.py``: the env vars ``REPRO_USE_PALLAS`` /
-``REPRO_PALLAS_INTERPRET`` gate the default path, per-call kwargs override.
-Callers (``repro.serving.paged_attn``) only ever see the same signatures
+The backend follows the platform (``repro.kernels.backend.resolve``): on
+a TPU the compiled kernel runs, and only a caller's explicit
+``use_pallas=False`` selects the jnp reference; off a TPU the reference
+runs unless ``REPRO_USE_PALLAS=1`` (or ``use_pallas=True``) selects the
+kernel, which then runs in interpret mode.  Callers
+(``repro.serving.paged_attn``) only ever see the same signatures
 regardless of backend:
 
     paged_attention(q, k_pool, v_pool, tables, positions, ...) -> out
@@ -14,7 +17,7 @@ regardless of backend:
     copy_page(pool, src, dst) -> pool                 # COW primitive
 
 With int8 pools (``kv_dtype="int8"``) every attention op also takes
-``k_scale=``/``v_scale=`` ((NB, BS, Hkv) fp32 per-row scale pools) and
+``k_scale=``/``v_scale=`` ((NB, Hkv, BS) fp32 per-row scale pools) and
 returns them updated: quantization is fused into the scatter, dequant
 into the page walk, with a bit-identical recipe on both backends
 (``ref.quantize_rows``).
@@ -32,34 +35,19 @@ psums/all-gather live in ``repro.serving.paged_attn``.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.paged_attention import ref as _ref
-
-_USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-
-
-def _default_interpret() -> bool:
-    """Interpret-mode default: the env var wins; otherwise interpret only
-    off-TPU.  This is the serving hot path — ``REPRO_USE_PALLAS=1`` alone
-    on real hardware must mean the *compiled* kernel, not the interpreter
-    (unlike training kernels, where the flash convention of defaulting
-    interpret on is harmless because configs opt in explicitly)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env == "1"
-    return jax.default_backend() != "tpu"
 
 
 def resolve(use_pallas: Optional[bool] = None,
             interpret: Optional[bool] = None) -> Tuple[bool, bool]:
-    """Effective (use_pallas, interpret) after env/backend defaulting."""
-    return (_USE_PALLAS if use_pallas is None else use_pallas,
-            _default_interpret() if interpret is None else interpret)
+    """Effective (use_pallas, interpret): the compiled kernel on a TPU,
+    the environment's choice elsewhere (see the module docstring)."""
+    return backend.resolve(use_pallas, interpret, tpu_default=True)
 
 
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
@@ -72,7 +60,7 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
                     v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Read-only paged attention.  q: (B, S, H, D) -> (B, S, H, D).
 
-    ``k_scale``/``v_scale`` ((NB, BS, Hkv) fp32, present iff the pools are
+    ``k_scale``/``v_scale`` ((NB, Hkv, BS) fp32, present iff the pools are
     int8 — ``kv_dtype="int8"``) select the fused-dequant walk on either
     backend.
     """

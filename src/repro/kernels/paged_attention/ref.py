@@ -3,9 +3,11 @@
 Layout (vLLM-style): per layer the K/V cache is a pool of ``num_blocks``
 pages of ``block_size`` tokens each —
 
-    k_pool, v_pool : (num_blocks, block_size, n_kv_heads, head_dim)
+    k_pool, v_pool : (num_blocks, n_kv_heads, block_size, head_dim)
 
-A request owns pages through a block table (logical block -> physical page);
+kv-head-major, so one page of one kv head is a contiguous (block_size,
+head_dim) tile — the block the Pallas kernel DMAs.  A request owns pages
+through a block table (logical block -> physical page);
 token position ``p`` lives at page ``table[p // bs]``, offset ``p % bs``.
 Physical page 0 is the null block: padded rows write there and nothing
 correct is ever read from it.
@@ -87,6 +89,31 @@ def copy_page(pool: jnp.ndarray, src, dst) -> jnp.ndarray:
     return pool.at[:, dst].set(pool[:, src])
 
 
+def _slots(positions: jnp.ndarray, block_tables: jnp.ndarray, BS: int):
+    """(page, offset) of every fresh row, flattened over (B, S).  Padded
+    rows (position -1) are routed to the null block's first slot."""
+    safe = jnp.maximum(positions, 0)
+    phys = jnp.take_along_axis(block_tables, safe // BS, axis=1)  # (B, S)
+    live = positions >= 0
+    return (jnp.where(live, phys, 0).reshape(-1),
+            jnp.where(live, safe % BS, 0).reshape(-1))
+
+
+def scatter_rows(pool: jnp.ndarray, rows: jnp.ndarray,
+                 positions: jnp.ndarray,
+                 block_tables: jnp.ndarray) -> jnp.ndarray:
+    """Write per-token rows into a kv-head-major pool (one layer).
+
+    pool : (NB, Hkv, BS, ...) — a K/V pool (trailing D) or a scale pool
+    rows : (B, S, Hkv, ...) matching trailing dims
+    Real rows hit distinct slots because every position belongs to
+    exactly one request; padded rows all land in the null block.
+    """
+    page, off = _slots(positions, block_tables, pool.shape[2])
+    rows = rows.reshape(-1, *rows.shape[2:]).astype(pool.dtype)
+    return pool.at[page, :, off].set(rows)
+
+
 def write_kv(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
              k: jnp.ndarray, v: jnp.ndarray,
              positions: jnp.ndarray, block_tables: jnp.ndarray,
@@ -94,39 +121,26 @@ def write_kv(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
              v_scale: Optional[jnp.ndarray] = None):
     """Scatter fresh K/V rows into their pages (one layer).
 
-    k_pool/v_pool : (NB, BS, Hkv, D)
+    k_pool/v_pool : (NB, Hkv, BS, D)
     k/v           : (B, S, Hkv, D) fresh projections
     positions     : (B, S) absolute token positions; -1 = padded row
     block_tables  : (B, MB) physical page ids
-    k_scale/v_scale : (NB, BS, Hkv) fp32 per-row scale pools — present iff
+    k_scale/v_scale : (NB, Hkv, BS) fp32 per-row scale pools — present iff
                       the KV pools are int8-quantized (``kv_dtype="int8"``)
 
-    Padded rows are routed to the null block (flat index 0).  Real rows hit
-    distinct slots because every position belongs to exactly one request.
     With scale pools, the fresh rows are quantized *here* (fused into the
     scatter — the pool never holds fp rows) and the matching scales land
-    in the same flat slots; returns a 4-tuple instead of 2.
+    in the same slots; returns a 4-tuple instead of 2.
     """
-    NB, BS, Hkv, D = k_pool.shape
-    safe = jnp.maximum(positions, 0)
-    phys = jnp.take_along_axis(block_tables, safe // BS, axis=1)  # (B, S)
-    flat = jnp.where(positions >= 0, phys * BS + safe % BS, 0).reshape(-1)
-    kf = k_pool.reshape(NB * BS, Hkv, D)
-    vf = v_pool.reshape(NB * BS, Hkv, D)
     if k_scale is not None:
-        kq, ks = quantize_rows(k.reshape(-1, Hkv, D))
-        vq, vs = quantize_rows(v.reshape(-1, Hkv, D))
-        kf = kf.at[flat].set(kq.astype(kf.dtype))
-        vf = vf.at[flat].set(vq.astype(vf.dtype))
-        ksf = k_scale.reshape(NB * BS, Hkv).at[flat].set(
-            ks.astype(k_scale.dtype))
-        vsf = v_scale.reshape(NB * BS, Hkv).at[flat].set(
-            vs.astype(v_scale.dtype))
-        return (kf.reshape(k_pool.shape), vf.reshape(v_pool.shape),
-                ksf.reshape(k_scale.shape), vsf.reshape(v_scale.shape))
-    kf = kf.at[flat].set(k.reshape(-1, Hkv, D).astype(kf.dtype))
-    vf = vf.at[flat].set(v.reshape(-1, Hkv, D).astype(vf.dtype))
-    return kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
+        kq, ks = quantize_rows(k)
+        vq, vs = quantize_rows(v)
+        return (scatter_rows(k_pool, kq, positions, block_tables),
+                scatter_rows(v_pool, vq, positions, block_tables),
+                scatter_rows(k_scale, ks, positions, block_tables),
+                scatter_rows(v_scale, vs, positions, block_tables))
+    return (scatter_rows(k_pool, k, positions, block_tables),
+            scatter_rows(v_pool, v, positions, block_tables))
 
 
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
@@ -146,25 +160,25 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
     live length point at pages whose k_pos exceeds every valid query
     position, so the causal mask hides them either way.
 
-    With ``k_scale``/``v_scale`` ((NB, BS, Hkv) fp32) the pools hold int8
+    With ``k_scale``/``v_scale`` ((NB, Hkv, BS) fp32) the pools hold int8
     rows and the gather dequantizes in fp32 before the dot — fused into
     the page walk exactly like the Pallas kernel's page loop.
     """
     B, S, H, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
+    NB, Hkv, BS, _ = k_pool.shape
     G = H // Hkv
     MB = block_tables.shape[1]
     L = MB if max_live_blocks is None else max(1, min(int(max_live_blocks),
                                                       MB))
     tables = block_tables[:, :L]
-    if k_scale is not None:
-        ck = dequantize(k_pool[tables], k_scale[tables]).reshape(
+
+    def pages(pool, scale):                 # (B, L*BS, Hkv, D) in q.dtype
+        x = pool[tables] if scale is None else \
+            dequantize(pool[tables], scale[tables])
+        return x.transpose(0, 1, 3, 2, 4).reshape(
             B, L * BS, Hkv, D).astype(q.dtype)
-        cv = dequantize(v_pool[tables], v_scale[tables]).reshape(
-            B, L * BS, Hkv, D).astype(q.dtype)
-    else:
-        ck = k_pool[tables].reshape(B, L * BS, Hkv, D).astype(q.dtype)
-        cv = v_pool[tables].reshape(B, L * BS, Hkv, D).astype(q.dtype)
+
+    ck, cv = pages(k_pool, k_scale), pages(v_pool, v_scale)
     qg = q.reshape(B, S, Hkv, G, D)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg * D ** -0.5, ck,
                    preferred_element_type=jnp.float32)

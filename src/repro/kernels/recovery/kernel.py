@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _fitness_kernel(il_ref, w_ref, target_ref, att_ref, limit_ref, out_ref):
@@ -77,7 +76,7 @@ def fitness_sq_pallas(il: jnp.ndarray, w: jnp.ndarray, target: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, bp), lambda p, e: (0, p)),
         out_shape=jax.ShapeDtypeStruct((1, P), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(il, w, target[None], att.reshape(1, 1), limit.reshape(1, 1))

@@ -7,17 +7,15 @@ sqrt + budget penalty are cheap elementwise tails and always run in jnp.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.recovery import ref as _ref
 from repro.kernels.recovery.ref import PENALTY_WEIGHT, recovery  # noqa: F401
 
-_USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
@@ -34,8 +32,7 @@ def basis_risk(il: jnp.ndarray, target: jnp.ndarray, w: jnp.ndarray,
                use_pallas: Optional[bool] = None,
                interpret: Optional[bool] = None) -> jnp.ndarray:
     """RMSE basis risk + budget penalty.  w: (..., m) -> (...)."""
-    use_pallas = _USE_PALLAS if use_pallas is None else use_pallas
-    interpret = _INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if not use_pallas:
         return _ref.basis_risk(il, target, w, att, limit, budget)
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
@@ -68,7 +67,7 @@ def wkv_pallas(r, k, v, w, u, *, chunk: int = 128,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

@@ -1,22 +1,19 @@
 """Jit'd wrapper for the RWKV-6 recurrence kernel: (B, S, H, D) API."""
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.wkv6 import ref as _ref
 
-_USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def wkv(r, k, v, w, u, *, use_pallas: Optional[bool] = None,
         interpret: Optional[bool] = None, chunk: int = 128) -> jnp.ndarray:
     """r/k/v/w: (B, S, H, D); u: (H, D) -> (B, S, H, D)."""
-    use_pallas = _USE_PALLAS if use_pallas is None else use_pallas
-    interpret = _INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if not use_pallas:
         return _ref.wkv(r, k, v, w, u)
     from repro.kernels.wkv6.kernel import wkv_pallas

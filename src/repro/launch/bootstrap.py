@@ -13,8 +13,13 @@ agnostic because shardings are expressed in global shapes.
 from __future__ import annotations
 
 import os
+import pathlib
 from dataclasses import dataclass
 from typing import Optional
+
+# fixed, inside the checkout: the cache directory is part of every entry's
+# key, so a path that moved between runs would never hit
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 @dataclass(frozen=True)
@@ -47,6 +52,25 @@ def detect() -> BootstrapInfo:
                              num_processes=-1, coordinator=None)
     return BootstrapInfo(launcher="single", process_id=0, num_processes=1,
                          coordinator=None)
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Keep compiled programs across runs; returns the cache directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    cache there and nothing is set in code.  Otherwise, on a TPU, the
+    cache goes to :data:`CACHE_DIR`; off a TPU compiles are cheap and
+    nothing is cached (``None``).  Call it from an entry point before the
+    first compile, never at import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if jax.default_backend() != "tpu":
+        return None
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
 
 def initialize(info: Optional[BootstrapInfo] = None) -> BootstrapInfo:

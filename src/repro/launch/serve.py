@@ -24,10 +24,11 @@ spill tier for evicted prefix-cache pages (DESIGN.md §13).  ``--trace
 PATH`` dumps the paged engine's telemetry
 trace after the run (DESIGN.md §10): JSONL, or a Chrome trace_event
 timeline when PATH ends in ``.json`` — summarize or validate it with
-``tools/tracestats.py``.  The attention backend follows ``REPRO_USE_PALLAS`` /
-``REPRO_PALLAS_INTERPRET`` (reference gather vs Pallas block-table-walk
-kernel) — no flags needed; the report's ``attention_backend`` field shows
-which one served.
+``tools/tracestats.py``.  The attention backend follows the platform: the
+compiled Pallas block-table-walk kernel on a TPU; off a TPU the reference
+gather, or the kernel in interpret mode under ``REPRO_USE_PALLAS=1`` — no
+flags needed; the report's ``attention_backend`` field shows which one
+served.
 
 ``--cluster NAME`` scales the paged engine out (DESIGN.md §7): the driver
 creates a named cluster through the platform verbs (``create_cluster`` over
@@ -322,8 +323,9 @@ def main(argv=None):
     ap.add_argument("--requests", type=int, default=32,
                     help="open-loop workload size")
     ap.add_argument("--seed", type=int, default=0,
-                    help="open-loop workload seed (pins arrivals AND "
-                         "request content)")
+                    help="pins the random weights and prompts, and the "
+                         "open-loop workload (arrivals AND request "
+                         "content)")
     ap.add_argument("--trace-file", default=None, metavar="PATH",
                     help="arrival trace to replay (--arrivals trace): "
                          "one float per line, or JSONL with t/"
@@ -334,6 +336,8 @@ def main(argv=None):
                     help="per-output-token SLO in seconds for the "
                          "goodput scorecard")
     args = ap.parse_args(argv)
+    from repro.launch.bootstrap import enable_compile_cache
+    enable_compile_cache()
 
     if args.engine != "batch" and args.temperature > 0:
         ap.error("--temperature is only supported with --engine batch "
@@ -373,8 +377,14 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    prompts = jax.random.randint(jax.random.PRNGKey(1),
+    key = jax.random.PRNGKey(args.seed)
+    if args.engine == "paged":
+        # a server holds its weights in the compute dtype from load
+        from repro.serving.paged_attn import init_serving_params
+        params = init_serving_params(cfg, key)
+    else:
+        params = M.init_params(cfg, key)
+    prompts = jax.random.randint(jax.random.PRNGKey(args.seed + 1),
                                  (args.batch, args.prompt_len), 0, cfg.vocab)
     t0 = time.time()
     if args.engine == "batch":
@@ -430,6 +440,9 @@ def main(argv=None):
         **extra,
     }
     print(json.dumps(report, indent=1))
+    if args.engine != "batch":
+        # callers that check the streams get them; the print stays short
+        report["results"] = results
     return report
 
 

@@ -165,7 +165,7 @@ def _local_dispatch_compute(xf, router_w, wg, wu, wi, wo, cfg, e0, E_loc,
 def apply_moe_shard_map(p: Params, x: jnp.ndarray, cfg,
                         info: "sharding.MeshInfo"):
     """Expert-parallel MoE with explicit per-device dispatch + one psum."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     m = cfg.moe
     E = m.num_experts
@@ -231,7 +231,7 @@ def apply_moe_shard_map(p: Params, x: jnp.ndarray, cfg,
     fn = shard_map(device_fn, mesh=info.mesh,
                    in_specs=tuple(in_specs),
                    out_specs=(P(x_batch_axes, None, None), P(None)),
-                   check_rep=False)
+                   check_vma=False)
     out, aux = fn(x, p["router"], *ws)
     return out, aux[0]
 

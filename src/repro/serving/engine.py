@@ -188,9 +188,11 @@ class PagedServingEngine:
             ``None`` (default) = unbounded.
         live_block_quantum: floor for the static live-block bound before
             power-of-two bucketing (bounds jit retraces).
-        use_pallas / interpret: attention backend override; ``None`` defers
-            to the ``REPRO_USE_PALLAS`` / ``REPRO_PALLAS_INTERPRET`` env
-            vars (reference jnp gather vs Pallas block-table-walk kernel).
+        use_pallas / interpret: attention backend override; ``None``
+            follows the platform (``paged_attention.ops.resolve``): the
+            compiled block-table-walk kernel on a TPU; off a TPU the jnp
+            reference gather, or the kernel in interpret mode under
+            ``REPRO_USE_PALLAS=1``.
         mesh: a ``jax.sharding.Mesh`` or a platform ``Cluster``
             (``Platform.create_cluster``) to shard the engine over.  With
             N > 1 devices on the mesh's model axis the weights, attention
@@ -230,7 +232,7 @@ class PagedServingEngine:
                  clock=None):
         assert paged_attn.supports(cfg), \
             "paged engine needs a pure-attention decoder-only arch"
-        # None defers to the REPRO_USE_PALLAS / REPRO_PALLAS_INTERPRET env
+        # None follows the platform: the compiled kernel on a TPU
         from repro.kernels.paged_attention import ops as paged_ops
         self.use_pallas, self.interpret = paged_ops.resolve(use_pallas,
                                                             interpret)
@@ -302,6 +304,9 @@ class PagedServingEngine:
             if plan.sharded:
                 self.tp = plan
 
+        # weights held in the compute dtype (exact; a no-op when the
+        # caller loaded them so, as launch/serve.py does)
+        params = paged_attn.hold_in_compute_dtype(cfg, params)
         self.params = params
         self.cache = paged_attn.init_paged_cache(cfg, self.num_blocks,
                                                  block_size,
@@ -411,7 +416,7 @@ class PagedServingEngine:
         else:
             from functools import partial
 
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             pspecs, cspecs = self._shard_specs
             rep = P(None, None)
@@ -420,13 +425,13 @@ class PagedServingEngine:
                 # one shard_map per tick: every shard advances its local
                 # kv heads / hidden slice; psums + the logits all-gather
                 # happen inside paged_step.  Built under jit, so `live`
-                # stays a static closure, and check_rep is off because the
+                # stays a static closure, and check_vma is off because the
                 # replicated outputs are only provably so to us, not to
                 # the rewriter (pallas calls are opaque to it).
                 fn = shard_map(partial(greedy_local, live=live),
                                mesh=self.mesh,
                                in_specs=(pspecs, cspecs, rep, rep, rep),
-                               out_specs=(rep, cspecs), check_rep=False)
+                               out_specs=(rep, cspecs), check_vma=False)
                 return fn(p, c, t, pos, bt)
 
             def greedy_unified(p, c, buf, live, chm, vw):
@@ -439,14 +444,14 @@ class PagedServingEngine:
                                in_specs=(pspecs, cspecs,
                                          *sharding.unified_batch_specs()),
                                out_specs=(P(None, None), cspecs),
-                               check_rep=False)
+                               check_vma=False)
                 return fn(p, c, buf)
 
             def cow_step(c, src, dst):
                 # page ids are global, each shard copies its kv-head slice
                 fn = shard_map(cow_local, mesh=self.mesh,
                                in_specs=(cspecs, P(), P()),
-                               out_specs=cspecs, check_rep=False)
+                               out_specs=cspecs, check_vma=False)
                 return fn(c, src, dst)
 
         # `live` is static: attention gathers/walks only that many blocks

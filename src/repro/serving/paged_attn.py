@@ -3,7 +3,9 @@
 Layout (vLLM-style): per layer the K/V cache is a pool of ``num_blocks``
 pages of ``block_size`` tokens each —
 
-    k, v : (L, num_blocks, block_size, n_kv_heads, head_dim)
+    k, v : (L, num_blocks, n_kv_heads, block_size, head_dim)
+
+(kv-head-major: one page of one kv head is the kernel's (BS, D) block).
 
 A request owns pages through a block table (logical block -> physical page);
 token position ``p`` of a request lives at page ``table[p // bs]``, offset
@@ -11,8 +13,8 @@ token position ``p`` of a request lives at page ``table[p // bs]``, offset
 padded rows write there and nothing correct is ever read from it.
 
 The attention op boundary lives in ``repro.kernels.paged_attention`` and
-has two backends behind one signature (``REPRO_USE_PALLAS`` env-gated,
-overridable per call):
+has two backends behind one signature (the compiled kernel on a TPU, the
+reference elsewhere unless ``REPRO_USE_PALLAS=1``; overridable per call):
 
   * reference — live-length gather: only the first ``max_live_blocks``
     table entries per row are materialised (the engine passes the tick's
@@ -63,6 +65,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -90,7 +93,7 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int,
 
     ``kv_dtype="int8"`` stores pages quantized: the K/V pools become int8
     and the dict grows parallel fp32 per-row scale pools ``k_scale`` /
-    ``v_scale`` of shape (L, NB, BS, Hkv) — one scale per (token row,
+    ``v_scale`` of shape (L, NB, Hkv, BS) — one scale per (token row,
     kv head), written by the fused quantizing scatter and read by the
     fused-dequant page walk.  ``None`` (or ``"fp"``) keeps the model
     dtype — the original layout, byte-compatible with every existing
@@ -101,7 +104,7 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int,
         raise ValueError(f"kv_dtype must be None|'fp'|'int8', "
                          f"got {kv_dtype!r}")
     dt = dtype or jnp.dtype(cfg.dtype)
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
+    shape = (cfg.n_layers, num_blocks, cfg.n_kv_heads, block_size,
              cfg.head_dim)
     if kv_dtype == "int8":
         sshape = shape[:-1]
@@ -110,6 +113,36 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int,
                 "k_scale": jnp.zeros(sshape, jnp.float32),
                 "v_scale": jnp.zeros(sshape, jnp.float32)}
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+# leaves the model uses in float32 (norms, the MoE router); every other
+# weight is cast to ``cfg.dtype`` at each use
+_F32_LEAVES = ("scale", "bias", "router")
+
+
+def hold_in_compute_dtype(cfg, params: Params) -> Params:
+    """Weights as a server holds them: every matrix in ``cfg.dtype``.
+
+    Exact: each use in the paged step (and in ``generate``) already casts
+    these weights to ``cfg.dtype``, so the token streams do not change,
+    while a bf16 config holds half the bytes of float32 masters and the
+    step no longer materialises a cast copy of them.  Leaves the model
+    reads in float32 keep the param dtype.
+    """
+    dt = jnp.dtype(cfg.dtype)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: (x if getattr(path[-1], "key", None) in _F32_LEAVES
+                         else x.astype(dt)),
+        params)
+
+
+def init_serving_params(cfg, key) -> Params:
+    """Random weights from ``key``, held in ``cfg.dtype`` from the start
+    (:func:`hold_in_compute_dtype`).  Init and cast are one jitted
+    program, so the float32 masters never exist whole on the device."""
+    from repro.models import model as M
+    return jax.jit(lambda k: hold_in_compute_dtype(
+        cfg, M.init_params(cfg, k)))(key)
 
 
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,

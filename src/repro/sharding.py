@@ -359,22 +359,22 @@ def unified_batch_specs() -> Tuple[P, ...]:
 
 
 def serving_cache_spec(plan: ServingTPPlan) -> P:
-    """Spec for one paged KV pool (L, num_blocks, block_size, Hkv, D):
+    """Spec for one paged KV pool (L, num_blocks, Hkv, block_size, D):
     kv heads over the model axis when attention is sharded, else
     replicated.  Every shard sees the full pool in *pages* either way —
     the block allocator's page ids are global."""
     if plan.shard_attn:
-        return P(None, None, None, plan.axis, None)
+        return P(None, None, plan.axis, None, None)
     return P(None, None, None, None, None)
 
 
 def serving_scale_spec(plan: ServingTPPlan) -> P:
-    """Spec for one quantization scale pool (L, num_blocks, block_size,
-    Hkv) — the per-row fp32 scales of an int8 KV pool
+    """Spec for one quantization scale pool (L, num_blocks, Hkv,
+    block_size) — the per-row fp32 scales of an int8 KV pool
     (``kv_dtype="int8"``).  Shards exactly like the pool it scales: kv
     heads over the model axis when attention shards, else replicated."""
     if plan.shard_attn:
-        return P(None, None, None, plan.axis)
+        return P(None, None, plan.axis, None)
     return P(None, None, None, None)
 
 

@@ -1,16 +1,22 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests see 1 CPU device;
 multi-device behaviour is tested via subprocess (test_multidevice.py,
-test_sharded_serving.py) through :func:`run_child` below."""
+test_sharded_serving.py) through :func:`run_child` below.
+
+Tier-1 runs on the CPU: ``JAX_PLATFORMS`` defaults to ``cpu`` before jax
+is imported, so the suite (and every child it starts) never takes a chip
+that another process needs."""
 import os
 import subprocess
 import sys
 import textwrap
 
-import jax
-import jax.numpy as jnp
-import pytest
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from repro.config import get_config, list_archs, reduced
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+from repro.config import get_config, list_archs, reduced  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

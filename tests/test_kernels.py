@@ -131,3 +131,39 @@ def test_pallas_attention_trainable_through_model():
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-3, rtol=5e-3)
+
+
+# ---------------------------------------------------------------------------
+# backend selection (repro.kernels.backend): the platform chooses
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("env", [None, "0", "1"])
+def test_backend_resolve_follows_platform(monkeypatch, tpu, env):
+    """The paged ops run the compiled kernel on a TPU whatever
+    REPRO_USE_PALLAS says; elsewhere the env var picks and the kernel runs
+    in interpret mode.  Other kernels stay opt-in on every platform."""
+    from repro.kernels import backend
+    from repro.kernels.paged_attention import ops as paged_ops
+    monkeypatch.setattr(backend, "on_tpu", lambda: tpu)
+    if env is None:
+        monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_USE_PALLAS", env)
+    opted_in = env == "1"
+    assert paged_ops.resolve() == (tpu or opted_in, not tpu)
+    assert backend.resolve(None, None) == (opted_in, not tpu)
+    # an explicit argument always wins: the reference on any platform
+    assert paged_ops.resolve(use_pallas=False) == (False, not tpu)
+
+
+def test_backend_refuses_interpret_on_tpu(monkeypatch):
+    from repro.kernels import backend
+    from repro.kernels.paged_attention import ops as paged_ops
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="interpret"):
+        paged_ops.resolve(interpret=True)
+    with pytest.raises(ValueError, match="interpret"):
+        backend.resolve(True, True)
+    monkeypatch.setattr(backend, "on_tpu", lambda: False)
+    assert backend.resolve(True, True) == (True, True)

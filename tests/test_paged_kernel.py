@@ -30,6 +30,13 @@ from repro.kernels.paged_attention import ref as paged_ref
 FULL = 1 << 30
 
 
+def head_major(pool):
+    """(NB, BS, Hkv, ...) -> the pools' (NB, Hkv, BS, ...) layout.  Cases
+    draw page contents slot by slot, so each (page, slot, head) holds the
+    same value whatever the layout."""
+    return jnp.swapaxes(pool, 1, 2)
+
+
 def make_case(seed, *, S, filled, ns, Hkv, G, BS, MB, D=16,
               dtype=jnp.float32):
     """Random pools/tables for one tick.
@@ -54,8 +61,9 @@ def make_case(seed, *, S, filled, ns, Hkv, G, BS, MB, D=16,
     arr = lambda *shape: jnp.asarray(
         rng.standard_normal(shape), jnp.float32).astype(dtype)
     return dict(q=arr(B, S, H, D), kn=arr(B, S, Hkv, D),
-                vn=arr(B, S, Hkv, D), kp=arr(NB, BS, Hkv, D),
-                vp=arr(NB, BS, Hkv, D), tables=jnp.asarray(tables),
+                vn=arr(B, S, Hkv, D), kp=head_major(arr(NB, BS, Hkv, D)),
+                vp=head_major(arr(NB, BS, Hkv, D)),
+                tables=jnp.asarray(tables),
                 pos=jnp.asarray(pos), np_pos=pos,
                 live=int(pos.max()) // BS + 1 if (pos >= 0).any() else 1)
 
@@ -194,11 +202,11 @@ def quantize_case(c, seed=0):
     rng = np.random.default_rng(seed)
     out = dict(c)
     for name in ("kp", "vp"):
-        shape = c[name].shape
-        out[name] = jnp.asarray(
-            rng.integers(-127, 128, size=shape), jnp.int8)
-        out[name[0] + "s"] = jnp.asarray(
-            np.abs(rng.standard_normal(shape[:-1])), jnp.float32)
+        NB, Hkv, BS, D = c[name].shape
+        out[name] = head_major(jnp.asarray(
+            rng.integers(-127, 128, size=(NB, BS, Hkv, D)), jnp.int8))
+        out[name[0] + "s"] = head_major(jnp.asarray(
+            np.abs(rng.standard_normal((NB, BS, Hkv))), jnp.float32))
     return out
 
 
@@ -288,10 +296,10 @@ def test_int8_copy_page_carries_scales():
     """COW on quantized pools: ``copy_page`` moves the int8 page AND its
     scale page (rank-generic pool handling), other pages persist."""
     rng = np.random.default_rng(21)
-    L, NB, BS, Hkv, D = 2, 5, 4, 2, 8
-    pool = jnp.asarray(rng.integers(-127, 128, (L, NB, BS, Hkv, D)),
+    L, NB, Hkv, BS, D = 2, 5, 2, 4, 8
+    pool = jnp.asarray(rng.integers(-127, 128, (L, NB, Hkv, BS, D)),
                        jnp.int8)
-    spool = jnp.asarray(np.abs(rng.standard_normal((L, NB, BS, Hkv))),
+    spool = jnp.asarray(np.abs(rng.standard_normal((L, NB, Hkv, BS))),
                         jnp.float32)
     for p in (pool, spool):
         got = paged_ops.copy_page(p, jnp.int32(1), jnp.int32(3),
@@ -339,8 +347,9 @@ def make_ragged_case(seed, *, segments, Hkv, G, BS, MB, D=16, pad=1,
     arr = lambda *shape: jnp.asarray(
         rng.standard_normal(shape), jnp.float32).astype(dtype)
     return dict(q=arr(T, 1, H, D), kn=arr(T, 1, Hkv, D),
-                vn=arr(T, 1, Hkv, D), kp=arr(NB, BS, Hkv, D),
-                vp=arr(NB, BS, Hkv, D), tables=jnp.asarray(tables),
+                vn=arr(T, 1, Hkv, D), kp=head_major(arr(NB, BS, Hkv, D)),
+                vp=head_major(arr(NB, BS, Hkv, D)),
+                tables=jnp.asarray(tables),
                 tables_req=jnp.asarray(tables_req),
                 pos=jnp.asarray(pos), np_pos=pos,
                 row_map=jnp.asarray(row_map),
@@ -510,8 +519,8 @@ def test_copy_page_parity(dtype):
     pool: the destination page carries the source's rows in every layer
     and every other page persists bit-identically (aliasing)."""
     rng = np.random.default_rng(11)
-    L, NB, BS, Hkv, D = 3, 6, 4, 2, 8
-    pool = jnp.asarray(rng.standard_normal((L, NB, BS, Hkv, D)),
+    L, NB, Hkv, BS, D = 3, 6, 2, 4, 8
+    pool = jnp.asarray(rng.standard_normal((L, NB, Hkv, BS, D)),
                        jnp.float32).astype(dtype)
     src, dst = 2, 5
     want = paged_ref.copy_page(pool, src, dst)

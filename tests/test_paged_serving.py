@@ -457,3 +457,50 @@ def test_paged_rejects_unsupported_archs(setup):
     rw = reduced(get_config("rwkv6-1.6b"))
     with pytest.raises(AssertionError):
         PagedServingEngine(rw, M.init_params(rw, jax.random.PRNGKey(0)))
+
+
+def test_serving_weights_held_in_compute_dtype(setup):
+    """Serving holds every matrix in ``cfg.dtype`` and keeps the leaves
+    the model reads in float32; the cast is exact, so a bf16 config
+    serves the same tokens from cast weights as ``generate`` does from
+    float32 masters."""
+    import dataclasses
+
+    from repro.serving.paged_attn import (hold_in_compute_dtype,
+                                          init_serving_params)
+    cfg, params = setup
+    cfg = dataclasses.replace(cfg, dtype="bfloat16")
+    held = hold_in_compute_dtype(cfg, params)
+    assert held["layers"]["attn"]["wq"].dtype == jnp.bfloat16
+    assert held["embed"]["table"].dtype == jnp.bfloat16
+    assert held["layers"]["ln1"]["scale"].dtype == jnp.float32
+    assert held["final_ln"]["scale"].dtype == jnp.float32
+    init = init_serving_params(cfg, jax.random.PRNGKey(0))
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: a.dtype == b.dtype and bool(jnp.array_equal(a, b)),
+        init, held))
+    prompt = np.arange(5, dtype=np.int32) % cfg.vocab
+    eng = PagedServingEngine(cfg, params, max_slots=1, block_size=4,
+                             max_blocks_per_seq=4)
+    rid = eng.submit(prompt, 4)
+    assert eng.run_to_completion()[rid] == _ref(cfg, params, prompt, 4)
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The compile cache honours JAX_COMPILATION_CACHE_DIR untouched and
+    otherwise sets a fixed directory inside the checkout — on a TPU only."""
+    from repro.launch import bootstrap
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert bootstrap.enable_compile_cache() == "/elsewhere/cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert bootstrap.enable_compile_cache() is None     # CPU: no cache
+    assert calls == []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    path = bootstrap.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert path == str(bootstrap.CACHE_DIR)
+    assert bootstrap.CACHE_DIR.parent == \
+        __import__("pathlib").Path(__file__).resolve().parents[1]

@@ -303,7 +303,8 @@ def test_serving_param_spec_rules():
     assert P("layers/ln1/scale", (2, 64), plan) == (None, None)
     assert P("lm_head/kernel", (64, 512), plan) == (None, "model")
     assert P("layers/moe/wg", (2, 4, 64, 64), plan) == (None,) * 4
-    assert serving_cache_spec(plan) == (None, None, None, "model", None)
+    # pools are (L, NB, Hkv, BS, D): kv heads over the model axis
+    assert serving_cache_spec(plan) == (None, None, "model", None, None)
 
     off = ServingTPPlan(axis="model", size=4, shard_attn=False,
                         shard_mlp=False, shard_vocab=False)
