@@ -238,6 +238,12 @@ def _call(q, k_new, v_new, k_pool, v_pool, block_tables, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        # the custom call's HLO instruction, and so its event in the
+        # profiler's device trace, is named after this, whatever the
+        # functions around the call are called: tools that find the
+        # kernel by name (its roofline in the benchmark) rely on it
+        name=("paged_attention_update_pallas" if fuse
+              else "paged_attention_pallas"),
     )(tables, positions, maxp, minp, win, *ins)
 
     out = res[0].reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4) \

@@ -1,4 +1,4 @@
-"""Allocation-cheap serving metrics: counters, gauges, and fixed-bucket
+"""Allocation-cheap serving metrics: counters and fixed-bucket
 histograms with interpolated percentiles.
 
 The serving hot path records one histogram sample per token and a handful
@@ -47,19 +47,6 @@ class Counter:
 
     def inc(self, n: Number = 1) -> None:
         self.value += n
-
-
-class Gauge:
-    """Point-in-time value (last write wins)."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.value: Number = 0
-
-    def set(self, v: Number) -> None:
-        self.value = v
 
 
 class Histogram:
@@ -160,15 +147,12 @@ class MetricsRegistry:
     def counter(self, name: str) -> Counter:
         return self._get(name, Counter)
 
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge)
-
     def histogram(self, name: str,
                   edges: Optional[Sequence[float]] = None) -> Histogram:
         return self._get(name, Histogram, edges)
 
     def snapshot(self) -> Dict[str, object]:
-        """Flat dict: counters/gauges -> value, histograms -> snapshot()."""
+        """Flat dict: counters -> value, histograms -> snapshot()."""
         out: Dict[str, object] = {}
         for name, m in sorted(self._metrics.items()):
             out[name] = m.snapshot() if isinstance(m, Histogram) else m.value

@@ -3,17 +3,28 @@
 :class:`ServingTelemetry` is the one observability object the serving
 stack shares (DESIGN.md §10): the engine records one structured
 :data:`tick` event per ``step()`` (dispatch kind, packed vs padded
-tokens, prefill/decode split, pool state, preemptions, host vs device
-time), the scheduler records request lifecycle :data:`span` events
-(submit -> admit -> first_token -> finish/preempt/cancel), and both feed
-the
-shared :class:`~repro.obs.metrics.MetricsRegistry` (TTFT / latency /
+tokens, prefill/decode split, pool state, preemptions, the host's
+wait on the device and its own phases), the scheduler records request
+lifecycle :data:`span` events (submit -> admit -> first_token ->
+finish/preempt/cancel), and both feed the shared
+:class:`~repro.obs.metrics.MetricsRegistry` (TTFT / latency /
 inter-token / queue-wait / tick-wall histograms, token counters).
 
 Everything is host-side and allocation-cheap: events are plain dicts in
 ``collections.deque`` rings (oldest dropped at capacity — ``dropped``
 counts what fell off, so exporters can say so), and a disabled instance
 (``enabled=False``) costs one attribute check per hook.
+
+Host phases: ``with telemetry.phase("pack"):`` marks one stretch of the
+serving path's host work (:data:`PHASES`).  It opens a
+``jax.profiler.TraceAnnotation`` named ``serve.<name>``, so whenever a
+profiler session runs the phase lies on the profiler's host plane, on
+the clock of the device's ops, and a device idle gap can be named by the
+host work beneath it.  It also adds the phase's self time (less any
+phase or garbage collection inside it) to the open tick's ``phase_s``.
+A Python garbage collection that interrupts a phase is itself the
+``gc`` phase, timed by one ``gc.callbacks`` hook per process.  Disabled,
+``phase()`` returns a shared null context: no clock read, no annotation.
 
 Exporters:
 
@@ -30,7 +41,10 @@ format from the command line.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
+import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
@@ -46,7 +60,11 @@ from repro.obs.metrics import MetricsRegistry
 # `swap_in` span kinds around preempt/resume, and the `vacate` span kind
 # (admission-dry slot giveback: pages returned without a policy
 # eviction, so admit counts stay balanced for the span-pairing check)
-SCHEMA_VERSION = 4
+# v5: per-tick host phases `phase_s` (self seconds of each PHASES entry
+# since the previous tick event), `dispatch_t`/`ready_t` (the jitted
+# call returned / the sync returned) and `compiled` (programs the tick's
+# dispatch added to the jit cache)
+SCHEMA_VERSION = 5
 
 # request lifecycle span kinds, in legal order of first appearance;
 # `finish` and `cancel` are the terminal kinds (at most one per request).
@@ -66,7 +84,70 @@ TICK_FIELDS = ("tick", "t", "kind", "wall_s", "host_s", "device_s",
                "live_slots", "waiting",
                "pool_free", "pool_cached", "pool_in_use",
                "prefix_hit_tokens", "preemptions", "cow_copies",
-               "dispatches", "finished", "swap_in", "swap_out", "quant")
+               "dispatches", "finished", "swap_in", "swap_out", "quant",
+               "phase_s", "dispatch_t", "ready_t", "compiled")
+
+# host phases of the serving path, each the profiler span ``serve.<name>``:
+# the front end's arrivals, token routing and finished-request harvest;
+# the engine's admission, tick packing, dispatch (until the jitted call
+# returns), sync (the host waits on the device), unpack and tick record;
+# and a garbage collection that interrupted any of them
+PHASES = ("pump", "route", "harvest", "admit", "pack", "dispatch", "sync",
+          "unpack", "record", "gc")
+_SPAN_NAMES = {p: f"serve.{p}" for p in PHASES}
+_NULL = contextlib.nullcontext()
+
+
+class _OpenPhases(threading.local):
+    """The phases open in this thread, innermost last."""
+
+    def __init__(self):
+        self.stack: List["_Phase"] = []
+
+
+_OPEN = _OpenPhases()
+
+
+class _Phase:
+    """One ``ServingTelemetry.phase()``: a profiler span, and on exit its
+    self time added to the telemetry's open tick."""
+
+    __slots__ = ("tel", "name", "ann", "t0", "inner")
+
+    def __init__(self, tel: "ServingTelemetry", name: str):
+        self.tel, self.name = tel, name
+        self.ann = tel._annotation(_SPAN_NAMES[name])
+        self.inner = 0.0            # seconds of phases nested inside
+
+    def __enter__(self):
+        self.ann.__enter__()
+        _OPEN.stack.append(self)
+        self.t0 = self.tel.clock()
+        return self
+
+    def __exit__(self, *exc):
+        took = self.tel.clock() - self.t0
+        stack = _OPEN.stack
+        stack.pop()
+        if stack:
+            stack[-1].inner += took
+        acc = self.tel._phase_acc
+        acc[self.name] = acc.get(self.name, 0.0) + took - self.inner
+        self.ann.__exit__(*exc)
+        return False
+
+
+def _on_gc(event: str, info: dict) -> None:
+    """The process's one ``gc.callbacks`` hook: a collection inside an
+    open phase is a ``gc`` phase of that phase's telemetry, so its time
+    is not the interrupted phase's.  A collection outside every phase is
+    not the serving path's and is not recorded."""
+    stack = _OPEN.stack
+    if event == "start":
+        if stack:
+            _Phase(stack[-1].tel, "gc").__enter__()
+    elif stack and stack[-1].name == "gc":
+        stack[-1].__exit__(None, None, None)
 
 
 class Ring:
@@ -114,7 +195,8 @@ class ServingTelemetry:
 
     Args:
         enabled: ``False`` turns every hook into a cheap no-op (no clock
-            reads, no ring appends) — the engine's ``telemetry=False``
+            reads, no ring appends, no profiler annotation, no
+            garbage-collection hook) — the engine's ``telemetry=False``
             escape hatch for overhead-sensitive benchmarking.
         capacity: tick-ring size; the span ring holds ``8 * capacity``
             (a tick touches at most a few lifecycle events per slot).
@@ -127,6 +209,13 @@ class ServingTelemetry:
         self.enabled = enabled
         self.clock = clock
         self.epoch: Optional[float] = None
+        # host phase -> self seconds since the last tick event
+        self._phase_acc: Dict[str, float] = {}
+        if enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            if _on_gc not in gc.callbacks:
+                gc.callbacks.append(_on_gc)
         self.registry = MetricsRegistry()
         self.ticks = Ring(capacity)
         self.spans = Ring(8 * capacity)
@@ -167,6 +256,14 @@ class ServingTelemetry:
         return t - self.epoch
 
     # -- recording hooks ------------------------------------------------
+    def phase(self, name: str):
+        """Context manager around one stretch of host work, ``name`` one
+        of :data:`PHASES`: the profiler span ``serve.<name>`` and the
+        phase's self time in the next tick event's ``phase_s``."""
+        if not self.enabled:
+            return _NULL
+        return _Phase(self, name)
+
     def span(self, req_id: int, kind: str, t: Optional[float] = None,
              **extra) -> None:
         """One request lifecycle event.  ``t`` is an absolute clock value
@@ -191,12 +288,19 @@ class ServingTelemetry:
                     cow_copies: int, dispatches: int,
                     finished: int, drafted: int = 0,
                     accepted: int = 0, swap_in: int = 0,
-                    swap_out: int = 0, quant: bool = False) -> None:
-        """One engine tick.  ``t``/``device_t`` are absolute clock values
-        (normalized here); everything else is this tick's delta or
-        point-in-time state."""
+                    swap_out: int = 0, quant: bool = False,
+                    dispatch_t: Optional[float] = None,
+                    ready_t: Optional[float] = None,
+                    compiled: int = 0) -> None:
+        """One engine tick.  ``t``/``device_t``/``dispatch_t``/``ready_t``
+        are absolute clock values (normalized here); everything else is
+        this tick's delta or point-in-time state.  ``device_s`` is the
+        host's wait from the dispatch to the sync's return, not the
+        device's busy time.  The event takes the host phases accumulated
+        since the previous one as ``phase_s``."""
         if not self.enabled:
             return
+        phase_s, self._phase_acc = self._phase_acc, {}
         host_s = max(0.0, wall_s - device_s)
         ev = {"type": "tick", "tick": self.ticks.total, "t": self._t(t),
               "kind": kind, "wall_s": wall_s, "host_s": host_s,
@@ -215,7 +319,11 @@ class ServingTelemetry:
               "preemptions": preemptions, "cow_copies": cow_copies,
               "dispatches": dispatches, "finished": finished,
               "swap_in": swap_in, "swap_out": swap_out,
-              "quant": bool(quant)}
+              "quant": bool(quant), "phase_s": phase_s,
+              "dispatch_t": None if dispatch_t is None
+              else self._t(dispatch_t),
+              "ready_t": None if ready_t is None else self._t(ready_t),
+              "compiled": compiled}
         self.ticks.append(ev)
         self.tick_wall_s.record(wall_s)
         self._c_ticks.inc()
@@ -303,7 +411,8 @@ class ServingTelemetry:
         "running" phase events (preemption closes a running phase and
         reopens queued) plus first-token / swap instant markers.  Ticks
         that moved pages across the host tier (DESIGN.md §13) also feed
-        a "swap pages" counter track.
+        a "swap pages" counter track, and each tick's ``phase_s`` feeds
+        a "host phases (ms)" counter track.
         """
         US = 1e6
         evs: List[dict] = [
@@ -332,6 +441,11 @@ class ServingTelemetry:
                             "name": "swap pages", "ts": ev["t"] * US,
                             "args": {"in": ev.get("swap_in", 0),
                                      "out": ev.get("swap_out", 0)}})
+            if ev.get("phase_s"):
+                evs.append({"ph": "C", "pid": 0, "cat": "phase",
+                            "name": "host phases (ms)", "ts": ev["t"] * US,
+                            "args": {k: v * 1e3 for k, v in
+                                     ev["phase_s"].items()}})
         per_req: Dict[int, list] = {}
         for s in self.spans.items():
             per_req.setdefault(s["req"], []).append(s)

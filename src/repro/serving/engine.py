@@ -81,6 +81,13 @@ class PagedRequest:
             [self.prompt, np.asarray(self.generated[:-1], np.int32)])
 
 
+def _jit_entries(fn) -> int:
+    """Programs in a jitted function's cache (a C++ count, no tracing);
+    0 for a plain callable standing in for one."""
+    size = getattr(fn, "_cache_size", None)
+    return size() if size is not None else 0
+
+
 class PagedServingEngine:
     """Continuous-batching serving engine over a paged KV cache.
 
@@ -292,6 +299,11 @@ class PagedServingEngine:
         self._tick_spec = [0, 0]       # [drafted, accepted] this tick
         self._tick_device_s = 0.0
         self._tick_device_t0: Optional[float] = None
+        # when this tick's first dispatch returned / its last sync
+        # returned, and the programs its dispatches added to the jit cache
+        self._tick_dispatch_t: Optional[float] = None
+        self._tick_ready_t: Optional[float] = None
+        self._tick_compiled = 0
         assert live_block_quantum >= 1
         self.live_block_quantum = live_block_quantum
 
@@ -1090,19 +1102,39 @@ class PagedServingEngine:
             self._tick_device_t0 = t
         return t
 
+    def _dispatched(self, fn, entries: int) -> None:
+        """Telemetry after a dispatch returned: the tick's first dispatch
+        time, and the programs the call added to ``fn``'s jit cache."""
+        if self._tick_dispatch_t is None:
+            self._tick_dispatch_t = self.telemetry.clock()
+        self._tick_compiled += _jit_entries(fn) - entries
+
+    def _synced(self, f0: float) -> None:
+        """Telemetry after a sync returned: the host's wait since the
+        dispatch began, and the tick's last sync time."""
+        self._tick_ready_t = self.telemetry.clock()
+        self._tick_device_s += self._tick_ready_t - f0
+
     def _run(self, tokens: np.ndarray, positions: np.ndarray,
              tables: np.ndarray) -> np.ndarray:
         """Legacy-tick dispatch: returns the (B, S) greedy next-token ids."""
-        fence = self.telemetry.enabled
-        t0 = self._fence_start() if fence else 0.0
-        next_tokens, self.cache = self._step_fn(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(tables),
-            self._live_bound(positions))
+        tel = self.telemetry
+        fence = tel.enabled
+        with tel.phase("dispatch"):
+            if fence:
+                t0 = self._fence_start()
+                entries = _jit_entries(self._step_fn)
+            next_tokens, self.cache = self._step_fn(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(positions), jnp.asarray(tables),
+                self._live_bound(positions))
+            if fence:
+                self._dispatched(self._step_fn, entries)
         self.dispatches += 1
-        out = np.asarray(next_tokens)   # blocks until the step is done
+        with tel.phase("sync"):
+            out = np.asarray(next_tokens)   # blocks until the step is done
         if fence:
-            self._tick_device_s += self.telemetry.clock() - t0
+            self._synced(t0)
         return out
 
     def _prefill_tick(self):
@@ -1239,6 +1271,32 @@ class PagedServingEngine:
         result (the dispatch/collect split behind ``step_begin``/
         ``step_end``).  Returns the in-flight context for
         :meth:`_unified_collect`, or None when there was nothing to pack.
+        """
+        tel = self.telemetry
+        with tel.phase("pack"):
+            packed = self._unified_pack()
+        if packed is None:
+            return None
+        buf, live, chm, vw, ctx = packed
+        fence = ctx["fence"] = tel.enabled
+        with tel.phase("dispatch"):
+            if fence:
+                ctx["f0"] = self._fence_start()
+                entries = _jit_entries(self._unified_fn)
+            ctx["next_tokens"], self.cache = self._unified_fn(
+                self.params, self.cache, jnp.asarray(buf), live, chm, vw)
+            if fence:
+                self._dispatched(self._unified_fn, entries)
+        self.dispatches += 1
+        # next_tokens is still a device future here: the host is free
+        # until _unified_collect's np.asarray sync — the open-loop front
+        # end admits newly arrived requests in that window
+        return ctx
+
+    def _unified_pack(self):
+        """Plan + pack the unified tick: returns (packed int32 buffer,
+        live-block bound, segment bound, verify width, context for
+        :meth:`_unified_collect`), or None when there is nothing to pack.
 
         ONE dispatch for the whole tick: decodes + prefill chunks packed
         into a flat ragged token batch under the scheduler's token split.
@@ -1397,17 +1455,8 @@ class PagedServingEngine:
         self._tick_pack = [T, Tb, T - sum(seg), sum(seg)]
         self._tick_spec[0] += drafted
         self.spec_drafted_total += drafted
-        fence = self.telemetry.enabled
-        f0 = self._fence_start() if fence else 0.0
-        next_tokens, self.cache = self._unified_fn(
-            self.params, self.cache, jnp.asarray(buf),
-            self._live_bound(positions), chm, vw)
-        self.dispatches += 1
-        # next_tokens is still a device future here: the host is free
-        # until _unified_collect's np.asarray sync — the open-loop front
-        # end admits newly arrived requests in that window
-        return {"next_tokens": next_tokens, "decoding": decoding,
-                "drafts": drafts, "plan": plan, "fence": fence, "f0": f0}
+        return (buf, self._live_bound(positions), chm, vw,
+                {"decoding": decoding, "drafts": drafts, "plan": plan})
 
     def _unified_collect(self, ctx: Optional[Dict[str, object]]
                          ) -> Dict[int, object]:
@@ -1419,30 +1468,34 @@ class PagedServingEngine:
         if ctx is None:
             return emitted
         decoding, drafts, plan = ctx["decoding"], ctx["drafts"], ctx["plan"]
-        next_tokens = np.asarray(ctx["next_tokens"])  # (max_slots, vw) blocks
+        tel = self.telemetry
+        with tel.phase("sync"):
+            # (max_slots, vw) ids; blocks until the tick is done
+            next_tokens = np.asarray(ctx["next_tokens"])
         if ctx["fence"]:
-            self._tick_device_s += self.telemetry.clock() - ctx["f0"]
-        # -- unpack -------------------------------------------------------
-        for slot in decoding:
-            self._accept(slot, drafts.get(slot, []), next_tokens[slot],
-                         emitted)
-        for slot, start, end in plan:
-            req = self.slot_req[slot]
-            self.slot_filled[slot] = end
-            self._register_pages(slot)
-            if end < self.slot_seq[slot].size:
-                continue  # more chunks to go
-            self.slot_phase[slot] = DECODE
-            if not req.generated:
-                # first generated token comes from the prompt's last logits
-                nxt = int(next_tokens[slot, 0])
-                req.generated.append(nxt)
-                emitted[req.req_id] = [nxt] if self.speculate else nxt
-                self.scheduler.on_token(req.req_id)
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish(slot)
-            elif self.speculate:
-                self._make_drafter(slot)
+            self._synced(ctx["f0"])
+        with tel.phase("unpack"):
+            for slot in decoding:
+                self._accept(slot, drafts.get(slot, []), next_tokens[slot],
+                             emitted)
+            for slot, start, end in plan:
+                req = self.slot_req[slot]
+                self.slot_filled[slot] = end
+                self._register_pages(slot)
+                if end < self.slot_seq[slot].size:
+                    continue  # more chunks to go
+                self.slot_phase[slot] = DECODE
+                if not req.generated:
+                    # first generated token comes from the prompt's last
+                    # logits
+                    nxt = int(next_tokens[slot, 0])
+                    req.generated.append(nxt)
+                    emitted[req.req_id] = [nxt] if self.speculate else nxt
+                    self.scheduler.on_token(req.req_id)
+                if len(req.generated) >= req.max_new_tokens:
+                    self._finish(slot)
+                elif self.speculate:
+                    self._make_drafter(slot)
         return emitted
 
     # ------------------------------------------------------------------
@@ -1476,6 +1529,8 @@ class PagedServingEngine:
             self._tick_pack = [0, 0, 0, 0]
             self._tick_device_s = 0.0
             self._tick_device_t0 = None
+            self._tick_dispatch_t = self._tick_ready_t = None
+            self._tick_compiled = 0
             # pre-tick counter snapshot: the tick event carries this
             # tick's deltas, not running totals (totals live in the meta
             # record)
@@ -1483,7 +1538,8 @@ class PagedServingEngine:
                            self.alloc.cow_copies, self.prefix_hit_tokens,
                            self.dispatches, len(self.finished))
             pend["t0"] = tel.clock()
-        self._admit()
+        with tel.phase("admit"):
+            self._admit()
         if self.unified:
             pend["ctx"] = self._unified_launch()
         else:
@@ -1511,28 +1567,33 @@ class PagedServingEngine:
         tel = self.telemetry
         if "t0" in pending:
             wall = tel.clock() - pending["t0"]
-            pre = pending["pre"]
-            in_use, cached, free = self.alloc.snapshot()
-            pk = self._tick_pack
-            n_emitted = (sum(len(v) for v in emitted.values())
-                         if self.speculate else len(emitted))
-            tel.record_tick(
-                t=pending["t0"], kind=pending["kind"], wall_s=wall,
-                device_s=self._tick_device_s,
-                device_t=self._tick_device_t0,
-                packed_tokens=pk[0], padded_tokens=pk[1],
-                prefill_tokens=pk[2], decode_tokens=pk[3],
-                drafted=self._tick_spec[0], accepted=self._tick_spec[1],
-                emitted=n_emitted, live_slots=self.active,
-                waiting=len(self.scheduler.waiting),
-                pool_free=free, pool_cached=cached, pool_in_use=in_use,
-                prefix_hit_tokens=self.prefix_hit_tokens - pre[2],
-                preemptions=self.scheduler.preemptions_total - pre[0],
-                cow_copies=self.alloc.cow_copies - pre[1],
-                dispatches=self.dispatches - pre[3],
-                finished=len(self.finished) - pre[4],
-                swap_in=self._tick_swap[0], swap_out=self._tick_swap[1],
-                quant=self.kv_dtype == "int8")
+            with tel.phase("record"):
+                pre = pending["pre"]
+                in_use, cached, free = self.alloc.snapshot()
+                pk = self._tick_pack
+                n_emitted = (sum(len(v) for v in emitted.values())
+                             if self.speculate else len(emitted))
+                tel.record_tick(
+                    t=pending["t0"], kind=pending["kind"], wall_s=wall,
+                    device_s=self._tick_device_s,
+                    device_t=self._tick_device_t0,
+                    packed_tokens=pk[0], padded_tokens=pk[1],
+                    prefill_tokens=pk[2], decode_tokens=pk[3],
+                    drafted=self._tick_spec[0],
+                    accepted=self._tick_spec[1],
+                    emitted=n_emitted, live_slots=self.active,
+                    waiting=len(self.scheduler.waiting),
+                    pool_free=free, pool_cached=cached, pool_in_use=in_use,
+                    prefix_hit_tokens=self.prefix_hit_tokens - pre[2],
+                    preemptions=self.scheduler.preemptions_total - pre[0],
+                    cow_copies=self.alloc.cow_copies - pre[1],
+                    dispatches=self.dispatches - pre[3],
+                    finished=len(self.finished) - pre[4],
+                    swap_in=self._tick_swap[0], swap_out=self._tick_swap[1],
+                    quant=self.kv_dtype == "int8",
+                    dispatch_t=self._tick_dispatch_t,
+                    ready_t=self._tick_ready_t,
+                    compiled=self._tick_compiled)
         return emitted
 
     def step(self) -> Dict[int, object]:

@@ -45,6 +45,7 @@ from typing import Dict, Iterator, List, Optional
 
 import numpy as np
 
+from repro.obs import ServingTelemetry
 from repro.serving.loadgen import TimedRequest, slo_report
 
 
@@ -116,6 +117,10 @@ class ServingFrontend:
                              "on a VirtualClock; it is meaningless on a "
                              "wall clock")
         self.virtual_tick_s = virtual_tick_s
+        # the front end's host phases go to the engine's telemetry (a
+        # replica router has none of its own)
+        self.telemetry = getattr(engine, "telemetry", None) or \
+            ServingTelemetry(enabled=False, capacity=1)
         self._arrivals: List = []            # heap of (t, req_id)
         self._reqs: Dict[int, FrontendRequest] = {}
         self._by_engine: Dict[int, FrontendRequest] = {}
@@ -215,9 +220,13 @@ class ServingFrontend:
             if not self._has_work():
                 out, self._fresh = self._fresh, {}
                 return out
-            before = (self.emitted_total, self.admitted_total)
+            # an arrival popped is progress even when it was cancelled
+            # and never reaches the engine
+            before = (self.emitted_total, self.admitted_total,
+                      len(self._arrivals))
             self._round()
-            if (self.emitted_total, self.admitted_total) != before:
+            if (self.emitted_total, self.admitted_total,
+                    len(self._arrivals)) != before:
                 last_fp = None
                 continue
             fp = self.engine._state_fingerprint()
@@ -310,16 +319,17 @@ class ServingFrontend:
     def _pump_arrivals(self) -> int:
         """Move every due arrival into the engine's scheduler queue."""
         n = 0
-        now = self.clock()
-        while self._arrivals and self._arrivals[0][0] <= now:
-            _, fid = heapq.heappop(self._arrivals)
-            fr = self._reqs[fid]
-            if fr.cancelled:
-                continue
-            fr.engine_id = self.engine.submit(fr.prompt,
-                                              fr.max_new_tokens)
-            self._by_engine[fr.engine_id] = fr
-            n += 1
+        with self.telemetry.phase("pump"):
+            now = self.clock()
+            while self._arrivals and self._arrivals[0][0] <= now:
+                _, fid = heapq.heappop(self._arrivals)
+                fr = self._reqs[fid]
+                if fr.cancelled:
+                    continue
+                fr.engine_id = self.engine.submit(fr.prompt,
+                                                  fr.max_new_tokens)
+                self._by_engine[fr.engine_id] = fr
+                n += 1
         self.admitted_total += n
         return n
 
@@ -343,16 +353,17 @@ class ServingFrontend:
 
     def _route(self, emitted: Dict[int, object]) -> None:
         """Mirror this tick's emitted tokens into front-end streams."""
-        now = self.clock()
-        for eid, v in emitted.items():
-            fr = self._by_engine.get(eid)
-            if fr is None:
-                continue
-            toks = list(v) if isinstance(v, list) else [v]
-            if fr.first_token_t is None and toks:
-                fr.first_token_t = now
-            fr.tokens.extend(toks)
-            self.emitted_total += len(toks)
+        with self.telemetry.phase("route"):
+            now = self.clock()
+            for eid, v in emitted.items():
+                fr = self._by_engine.get(eid)
+                if fr is None:
+                    continue
+                toks = list(v) if isinstance(v, list) else [v]
+                if fr.first_token_t is None and toks:
+                    fr.first_token_t = now
+                fr.tokens.extend(toks)
+                self.emitted_total += len(toks)
 
     def _harvest_finished(self) -> None:
         """Fold engine-finished requests into front-end records and drop
@@ -360,20 +371,21 @@ class ServingFrontend:
         eng = self.engine
         if not eng.finished:
             return
-        now = self.clock()
-        for eid, req in eng.finished.items():
-            fr = self._by_engine.pop(eid, None)
-            if fr is None:
-                continue    # submitted directly on the engine, not ours
-            if fr.tokens != req.generated:
-                raise AssertionError(
-                    f"req {fr.req_id}: streamed tokens diverge from the "
-                    f"engine's record ({len(fr.tokens)} streamed vs "
-                    f"{len(req.generated)} generated)")
-            fr.done = True
-            fr.finished_t = now
-            fr.oom = req.oom
-            fr.cancelled = fr.cancelled or req.cancelled
-            if not fr.cancelled:
-                self._fresh[fr.req_id] = fr.tokens
-        eng.clear_finished()
+        with self.telemetry.phase("harvest"):
+            now = self.clock()
+            for eid, req in eng.finished.items():
+                fr = self._by_engine.pop(eid, None)
+                if fr is None:
+                    continue    # submitted directly on the engine, not ours
+                if fr.tokens != req.generated:
+                    raise AssertionError(
+                        f"req {fr.req_id}: streamed tokens diverge from "
+                        f"the engine's record ({len(fr.tokens)} streamed "
+                        f"vs {len(req.generated)} generated)")
+                fr.done = True
+                fr.finished_t = now
+                fr.oom = req.oom
+                fr.cancelled = fr.cancelled or req.cancelled
+                if not fr.cancelled:
+                    self._fresh[fr.req_id] = fr.tokens
+            eng.clear_finished()

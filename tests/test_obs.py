@@ -23,7 +23,7 @@ import json
 import numpy as np
 import pytest
 
-from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Ring,
+from repro.obs import (PHASES, Counter, Histogram, MetricsRegistry, Ring,
                        SPAN_KINDS, TICK_FIELDS, ServingTelemetry,
                        log_bucket_edges)
 from repro.serving.scheduler import FCFSScheduler
@@ -87,16 +87,15 @@ def test_registry_get_or_create_and_type_guard():
     c = r.counter("a")
     c.inc(3)
     assert r.counter("a") is c and r.counter("a").value == 3
-    g = r.gauge("b")
-    g.set(7)
     h = r.histogram("c")
     h.record(0.5)
     with pytest.raises(TypeError):
-        r.gauge("a")                    # 'a' is already a Counter
+        r.histogram("a")                # 'a' is already a Counter
+    with pytest.raises(TypeError):
+        r.counter("c")                  # 'c' is already a Histogram
     snap = r.snapshot()
-    assert snap["a"] == 3 and snap["b"] == 7 and snap["c"]["count"] == 1
+    assert snap["a"] == 3 and snap["c"]["count"] == 1
     assert isinstance(Counter("x").value, int)
-    assert isinstance(Gauge("x").value, int)
 
 
 def test_ring_wraparound():
@@ -145,7 +144,7 @@ def test_trace_jsonl_schema_and_pairing(tmp_path):
     from tools import tracestats
     meta, ticks, spans, fmt = tracestats.load(str(path))
     assert fmt == "jsonl"
-    assert meta["schema"] == 4 and meta["engine"] == {"extra": 1}
+    assert meta["schema"] == 5 and meta["engine"] == {"extra": 1}
     assert len(ticks) == 2 and len(spans) == 10
     for t in ticks:
         for f in TICK_FIELDS:
@@ -229,7 +228,7 @@ def test_trace_chrome_export(tmp_path):
     doc = json.loads(path.read_text())  # must be valid JSON
     evs = doc["traceEvents"]
     assert evs, "empty traceEvents"
-    assert doc["metadata"]["schema"] == 4
+    assert doc["metadata"]["schema"] == 5
     phases = {e["ph"] for e in evs}
     assert phases >= {"M", "X", "i"}    # metadata, complete, instant
     tick_evs = [e for e in evs if e.get("cat") == "tick"]
@@ -460,3 +459,192 @@ def test_trace_ring_bounded_on_engine(setup):
     assert tel.ticks.dropped > 0
     # running counters keep the full history the ring dropped
     assert tel.registry.counter("ticks").value == tel.ticks.total
+
+
+# ---------------------------------------------------------------------
+# host phases (schema 5): ``serve.*`` profiler spans + per-tick phase_s
+# ---------------------------------------------------------------------
+def _record(tel, t=0.0):
+    """Close the open tick; returns its ``phase_s``."""
+    tel.record_tick(t=t, kind="unified", wall_s=0.0, device_s=0.0,
+                    device_t=None, packed_tokens=0, padded_tokens=0,
+                    prefill_tokens=0, decode_tokens=0, emitted=0,
+                    live_slots=0, waiting=0, pool_free=0, pool_cached=0,
+                    pool_in_use=0, prefix_hit_tokens=0, preemptions=0,
+                    cow_copies=0, dispatches=0, finished=0)
+    return tel.ticks.items()[-1]["phase_s"]
+
+
+def test_phase_self_times_do_not_overlap():
+    # the fake clock moves one second per read
+    tel = ServingTelemetry(clock=_fake_clock())
+    with tel.phase("pack"):                 # 0
+        with tel.phase("dispatch"):         # 1 .. 2
+            pass
+        with tel.phase("sync"):             # 3 .. 4
+            pass
+    # pack ran 0 .. 5 with 2 s of phases inside it
+    assert _record(tel) == {"dispatch": 1.0, "sync": 1.0, "pack": 3.0}
+    assert _record(tel) == {}               # the next tick starts empty
+
+
+def test_gc_time_is_taken_from_the_phase_it_interrupts():
+    import gc
+
+    from repro.obs import trace
+    tel = ServingTelemetry(clock=_fake_clock())
+    trace._on_gc("start", {})               # outside every phase: ignored
+    trace._on_gc("stop", {})
+    with tel.phase("unpack"):               # 0
+        trace._on_gc("start", {})           # 1
+        trace._on_gc("stop", {})            # 2
+    assert _record(tel) == {"gc": 1.0, "unpack": 2.0}
+    # a real collection inside a phase, on the real clock
+    real = ServingTelemetry()
+    with real.phase("route"):
+        gc.collect()
+    phases = _record(real)
+    assert phases["gc"] > 0 and phases["route"] >= 0
+
+
+def test_unknown_phase_is_refused():
+    with pytest.raises(KeyError):
+        ServingTelemetry().phase("bogus")
+    assert set(PHASES) >= {"pump", "route", "harvest", "sync", "gc"}
+
+
+def _serve(eng, n=3, gen=5, seed=0):
+    from repro.serving.frontend import ServingFrontend
+    fe = ServingFrontend(eng)
+    rng = np.random.default_rng(seed)
+    for _ in range(n):
+        fe.submit(rng.integers(0, eng.cfg.vocab, 7).astype(np.int32), gen)
+    return fe.drain()
+
+
+def test_disabled_telemetry_makes_no_span_reads_no_clock(setup,
+                                                        monkeypatch):
+    """``telemetry=False``: the serving path builds no profiler
+    annotation, reads no clock for telemetry and registers no garbage
+    collection hook."""
+    import gc
+
+    import jax.profiler
+
+    from repro.obs import trace
+    from repro.serving import PagedServingEngine
+    cfg, params = setup
+    made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: made.append(name))
+    hooked = trace._on_gc in gc.callbacks
+    while trace._on_gc in gc.callbacks:
+        gc.callbacks.remove(trace._on_gc)
+    try:
+        eng = PagedServingEngine(cfg, params, max_slots=2, block_size=4,
+                                 max_blocks_per_seq=12, prefill_chunk=4,
+                                 telemetry=False)
+
+        def no_clock():
+            raise AssertionError("disabled telemetry read the clock")
+        eng.telemetry.clock = no_clock
+        assert len(_serve(eng)) == 3
+        assert made == [] and trace._on_gc not in gc.callbacks
+        assert eng.telemetry.phase("pack") is eng.telemetry.phase("sync")
+        # an enabled instance does build them
+        ServingTelemetry().phase("pack")
+        assert made == ["serve.pack"]
+    finally:
+        if hooked and trace._on_gc not in gc.callbacks:
+            gc.callbacks.append(trace._on_gc)
+
+
+def test_one_gc_hook_for_many_engines(setup):
+    import gc
+    import weakref
+
+    from repro.obs import trace
+    from repro.serving import PagedServingEngine
+    cfg, params = setup
+    engines = [PagedServingEngine(cfg, params, max_slots=1, block_size=4,
+                                  max_blocks_per_seq=4)
+               for _ in range(4)]
+    assert gc.callbacks.count(trace._on_gc) == 1
+    ref = weakref.ref(engines[0])
+    del engines
+    gc.collect()
+    assert ref() is None                    # the hook holds no engine
+
+
+def test_engine_trace_phases_pass_tracestats(setup, tmp_path):
+    """A schema-5 dump of a served run passes ``tracestats --check``;
+    copies broken in each of the phase checks fail it."""
+    from repro.serving import PagedServingEngine
+    from tools import tracestats
+    cfg, params = setup
+    eng = PagedServingEngine(cfg, params, max_slots=2, block_size=4,
+                             max_blocks_per_seq=12, prefill_chunk=4)
+    _serve(eng)
+    path = tmp_path / "trace.jsonl"
+    eng.dump_trace(path)
+    assert tracestats.main([str(path), "--check"]) == 0
+    meta, ticks, spans, _ = tracestats.load(str(path))
+    seen = set().union(*(t["phase_s"] for t in ticks))
+    assert seen <= set(PHASES)
+    assert {"pump", "route", "harvest", "admit", "pack", "dispatch",
+            "sync", "unpack", "record"} <= seen
+    assert all(t["ready_t"] >= t["dispatch_t"] for t in ticks)
+
+    def negative(tk):
+        tk[1]["phase_s"] = dict(tk[1]["phase_s"], pack=-1e-3)
+
+    def too_long(tk):
+        tk[2]["phase_s"] = dict(tk[2]["phase_s"], pump=10.0)
+
+    def early_ready(tk):
+        tk[1]["ready_t"] = tk[1]["dispatch_t"] - 1e-3
+
+    def missing(tk):
+        del tk[0]["phase_s"]
+
+    for breaks, says in ((negative, "negative phase"),
+                         (too_long, "since the previous tick"),
+                         (early_ready, "before dispatch_t"),
+                         (missing, "missing fields")):
+        bad = [dict(t) for t in ticks]
+        breaks(bad)
+        errs = tracestats.check(meta, bad, spans,
+                                tracestats.summarize(meta, bad, spans))
+        assert any(says in e for e in errs), (says, errs)
+    # the Chrome export draws the phases as a counter track
+    cpath = tmp_path / "trace.json"
+    eng.dump_trace(cpath)
+    evs = json.loads(cpath.read_text())["traceEvents"]
+    assert any(e.get("cat") == "phase" and e["ph"] == "C" for e in evs)
+
+
+def test_compiled_names_the_tick_that_compiled(setup):
+    from repro.serving import PagedServingEngine
+    cfg, params = setup
+    eng = PagedServingEngine(cfg, params, max_slots=1, block_size=4,
+                             max_blocks_per_seq=12, prefill_chunk=4)
+    eng.submit(np.arange(4, dtype=np.int32), 6)
+    eng.run_to_completion()
+    compiled = [t["compiled"] for t in eng.telemetry.ticks.items()]
+    # a 4-token prefill chunk, then the first decode: each a new shape;
+    # every later decode reuses the decode program
+    assert compiled == [1, 1, 0, 0, 0, 0]
+    assert sum(compiled) == eng._unified_fn._cache_size()
+
+
+def test_legacy_tick_records_dispatch_and_sync(setup):
+    from repro.serving import PagedServingEngine
+    cfg, params = setup
+    eng = PagedServingEngine(cfg, params, max_slots=2, block_size=4,
+                             max_blocks_per_seq=12, prefill_chunk=4,
+                             unified=False)
+    _serve(eng, n=2)
+    ticks = eng.telemetry.ticks.items()
+    assert all({"dispatch", "sync"} <= set(t["phase_s"]) for t in ticks)
+    assert all(t["ready_t"] >= t["dispatch_t"] for t in ticks)
+    assert sum(t["compiled"] for t in ticks) == eng._step_fn._cache_size()

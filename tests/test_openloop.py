@@ -229,6 +229,23 @@ def test_cancel_waiting_and_before_arrival(setup):
     assert eng.metrics()["scheduler"]["cancelled"] == 1
 
 
+def test_drain_pops_cancelled_future_arrivals(setup):
+    """Two cancelled arrivals still in the future with the engine idle:
+    each round of ``drain`` only pops one, and that is progress, not a
+    livelock."""
+    cfg, params = setup
+    vc = VirtualClock()
+    eng = _open_engine(cfg, params, vc, max_slots=2)
+    fe = ServingFrontend(eng, virtual_tick_s=0.001)
+    prompt = np.arange(1, dtype=np.int32)
+    assert fe.cancel(fe.submit(prompt, 1, at=vc() + 0.002))
+    kept = fe.submit(prompt, 1)
+    fe._round()
+    assert fe.cancel(fe.submit(prompt, 1, at=vc() + 0.002))
+    assert fe.drain() == {kept: fe.result(kept).tokens}
+    assert not fe._arrivals and eng.active == 0
+
+
 def test_cancel_refused_while_tick_in_flight(setup):
     """A slot-held request cannot be cancelled mid-dispatch (its tokens
     are packed into the running tick); the front end defers instead."""

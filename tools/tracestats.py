@@ -30,6 +30,10 @@ violation):
     on pure-decode ticks ``emitted == decode_tokens - drafted +
     accepted`` (the rejected draft tail is the only packed-vs-emitted
     gap); drafted/accepted sums match the ``spec.*`` running counters;
+  * host phases (schema 5): every ``phase_s`` entry is non-negative,
+    the phases of a tick sum to no more than the time since the
+    previous tick event ended (phases do not overlap, and a tick event
+    takes those since the last one), and ``ready_t >= dispatch_t``;
   * KV capacity tiers (DESIGN.md §13): per tick, swapped-in pages never
     exceed the device pool's capacity (``pool_free + pool_cached +
     pool_in_use``), the ``quant`` flag is constant across the trace (pool
@@ -66,7 +70,7 @@ except ImportError:                                   # pragma: no cover
                    "pool_free", "pool_cached", "pool_in_use",
                    "prefix_hit_tokens", "preemptions", "cow_copies",
                    "dispatches", "finished", "swap_in", "swap_out",
-                   "quant")
+                   "quant", "phase_s", "dispatch_t", "ready_t", "compiled")
 
 
 def load(path: str):
@@ -208,15 +212,49 @@ def summarize(meta, ticks, spans) -> dict:
     return out
 
 
+def check_phases(ticks) -> list:
+    """Schema-5 host phases: non-negative, no more than the time since
+    the previous tick event ended, and the sync after the dispatch."""
+    errs = []
+    prev = None
+    for t in ticks:
+        phases = t.get("phase_s")
+        if phases is None:
+            continue
+        if any(v < 0 for v in phases.values()):
+            errs.append(f"tick {t['tick']}: negative phase in {phases}")
+            break
+        end = t["t"] + t["wall_s"]
+        if prev is not None and prev["tick"] + 1 == t["tick"]:
+            since = end - (prev["t"] + prev["wall_s"])
+            # 1 ns: float rounding of the epoch-relative times
+            if sum(phases.values()) > since + 1e-9:
+                errs.append(f"tick {t['tick']}: phases sum to "
+                            f"{sum(phases.values()):.6f} s, more than the "
+                            f"{since:.6f} s since the previous tick")
+                break
+        prev = t
+        d, r = t.get("dispatch_t"), t.get("ready_t")
+        if d is not None and r is not None and r < d:
+            errs.append(f"tick {t['tick']}: ready_t {r} before "
+                        f"dispatch_t {d}")
+            break
+    return errs
+
+
 def check(meta, ticks, spans, summary) -> list:
     """Structural gates; returns the list of violations (empty = pass)."""
     errs = []
     if not ticks:
         errs.append("trace has no tick events")
         return errs
-    # pre-v4 traces predate the capacity-tier fields; don't fail archives
-    required = TICK_FIELDS if meta.get("schema", 0) >= 4 else tuple(
-        f for f in TICK_FIELDS if f not in ("swap_in", "swap_out", "quant"))
+    # older traces predate the capacity-tier (v4) and host-phase (v5)
+    # fields; don't fail archives
+    schema = meta.get("schema", 0)
+    newer = (() if schema >= 5 else
+             ("phase_s", "dispatch_t", "ready_t", "compiled")) + (
+        () if schema >= 4 else ("swap_in", "swap_out", "quant"))
+    required = tuple(f for f in TICK_FIELDS if f not in newer)
     for t in ticks:
         missing = [f for f in required if f not in t]
         if missing:
@@ -242,6 +280,7 @@ def check(meta, ticks, spans, summary) -> list:
                         f"decode_tokens {t['decode_tokens']} - drafted "
                         f"{drafted} + accepted {accepted}")
             break
+    errs.extend(check_phases(ticks))
     # KV capacity tiers (DESIGN.md §13): a tick cannot stream in more
     # pages than the device pool can hold, and the pool's quantization
     # never changes mid-run
