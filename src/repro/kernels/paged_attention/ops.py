@@ -24,7 +24,7 @@ into the page walk, with a bit-identical recipe on both backends
 
 The reference path is the live-length oracle in ``ref.py`` (update =
 scatter via ``ref.write_kv`` then gather); the Pallas path walks block
-tables in place with the scatter fused into the kernel prologue.
+tables in place with the scatter fused into the walk.
 
 Both backends are shard-oblivious: on a cluster-sharded engine
 (DESIGN.md §7) these ops run *inside* the step's ``shard_map``, so q and
@@ -173,14 +173,15 @@ def paged_attention_unified(q: jnp.ndarray, k_new: jnp.ndarray,
     ``row_map`` into a (R, max_seg_len) padded multi-query view and run
     through :func:`paged_attention_update` — one live-length page
     walk/gather per request with intra-chunk causal masking (for the
-    Pallas backend that is the block-table-walk kernel on a
-    (R, max_seg_len) grid, fused scatter included).  Walking the flat
+    Pallas backend that is the block-table-walk kernel over the R
+    requests' own pages, fused scatter included).  Walking the flat
     rows directly would instead re-read every segment's pages once per
     token — chunk-width times the page traffic.
 
     Intra-chunk causality holds because a segment's fresh K/V rows are
     all scattered into their pages before (reference) or while (Pallas
-    prologue) its queries attend, and the causal mask orders them.
+    walk, before the page is attended) its queries attend, and the
+    causal mask orders them.
 
     Segments are agnostic to what the tokens *are*: a prefill chunk and
     a speculative draft chain (DESIGN.md §11 — last accepted token +

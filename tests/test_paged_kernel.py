@@ -10,6 +10,11 @@ Contracts under test:
     including pages it never visits (input/output aliasing);
   * live-block early exit — walking only ``max_live_blocks`` blocks gives
     the same answer as gathering the full table width;
+  * the walk's geometry — each row walks its own live pages in compute
+    blocks whose size the kernel derives from the shapes: rows of very
+    different depths beside padded rows, live lengths ending mid-page and
+    mid-block, chunks straddling two blocks, pages shared by two rows, and
+    no write outside the fresh pages, in any layer's range of the pool;
   * unified ragged mode — the flat one-token-per-row batch of the unified
     tick (decode rows + prefill segments, walked per request through the
     ``row_map`` view) matches the flat scatter-first oracle on BOTH
@@ -24,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention import ref as paged_ref
 
@@ -308,6 +314,118 @@ def test_int8_copy_page_carries_scales():
         assert jnp.array_equal(want, got)
         keep = [i for i in range(NB) if i != 3]
         assert jnp.array_equal(got[:, keep], p[:, keep])
+
+
+# ---------------------------------------------------------------------------
+# the walk: each row's own live pages, in multi-page compute blocks
+# ---------------------------------------------------------------------------
+
+def walk_plan(c):
+    """(pages per compute block, query rows per tile) the kernel derives
+    for case ``c``."""
+    B, S, H, D = c["q"].shape
+    _, Hkv, BS, _ = c["kp"].shape
+    _, P, QB, _, _ = paged_kernel._plan(
+        SG=S * (H // Hkv), Hkv=Hkv, BS=BS, D=D, nb=c["live"],
+        q_dtype=c["q"].dtype, pool_dtype=c["kp"].dtype, NF=1, quant=False)
+    return P, QB
+
+
+@pytest.mark.parametrize("window", [FULL, 100])
+@pytest.mark.parametrize("filled,ns", [
+    ([1100, 0, 37, 0, 300], [1, 1, 1, 0, 1]),  # 1 page .. 2 blocks, padded
+    ([1057, 0, 16, 250], [1, 0, 1, 1]),        # ends mid-page, mid-block
+])
+def test_walk_rows_of_mixed_depths(filled, ns, window):
+    """Rows of very different depths and padded rows in one call: each
+    row stops at its own depth, the deepest walks two compute blocks (a
+    window of 100 starts its walk in the last pages of the first, which
+    its query sees only in part)."""
+    c = make_case(60 + len(filled), S=1, filled=filled, ns=ns, Hkv=2, G=2,
+                  BS=16, MB=72)
+    assert walk_plan(c)[0] < c["live"]
+    out_r, pools_r, out_k, pools_k = run_both(c, window=window, softcap=0.0)
+    assert np.isfinite(np.asarray(out_k)).all()
+    assert_parity(c, out_r, pools_r, out_k, pools_k)
+
+
+@pytest.mark.parametrize("window", [FULL, 50])
+def test_walk_chunk_spans_two_blocks(window):
+    """A chunk at 256 query rows whose fresh rows 1010..1041 straddle the
+    boundary of two 1024-key blocks and two page boundaries: every page
+    is written before either block attends."""
+    c = make_case(71, S=64, filled=[1010, 100, 0], ns=[32, 64, 0], Hkv=1,
+                  G=4, BS=16, MB=70)
+    P, QB = walk_plan(c)
+    assert (P, QB) == (64, 256) and (1010 // 16) // P != (1041 // 16) // P
+    assert_parity(c, *run_both(c, window=window, softcap=0.0))
+
+
+def test_walk_shared_prefix_page():
+    """Two rows read the same full pages, as the prefix cache shares
+    them, while each writes only its own tail page."""
+    c = make_case(81, S=2, filled=[40, 45], ns=[2, 2], Hkv=2, G=2, BS=16,
+                  MB=8)
+    tables = np.array(c["tables"])
+    tables[1, :2] = tables[0, :2]            # positions 0..31, both rows
+    c["tables"] = jnp.asarray(tables)
+    out_r, pools_r, out_k, pools_k = run_both(c, window=FULL, softcap=0.0)
+    assert_parity(c, out_r, pools_r, out_k, pools_k)
+    for before, after in zip((c["kp"], c["vp"]), pools_k):
+        np.testing.assert_array_equal(np.asarray(after)[tables[0, :2]],
+                                      np.asarray(before)[tables[0, :2]])
+
+
+def test_walk_writes_only_fresh_pages():
+    """On a flat pool of three layers, walked pages that hold no fresh
+    row and every page of the other layers' ranges are byte-identical
+    after the call; the fresh pages are the reference's."""
+    c = make_case(91, S=4, filled=[37, 0, 70, 0], ns=[4, 0, 3, 1], Hkv=2,
+                  G=2, BS=16, MB=8)
+    NB = c["kp"].shape[0]
+    rng = np.random.default_rng(92)
+
+    def three_layers(pool):
+        other = lambda: jnp.asarray(  # noqa: E731
+            rng.standard_normal(pool.shape), pool.dtype)
+        return jnp.concatenate([other(), pool, other()])
+    c["kp"], c["vp"] = three_layers(c["kp"]), three_layers(c["vp"])
+    c["tables"] = c["tables"] + NB               # the middle layer's pages
+    out_r, (kr, vr), out_k, pools_k = run_both(c, window=FULL, softcap=0.0)
+    valid = c["np_pos"] >= 0
+    np.testing.assert_allclose(np.asarray(out_r)[valid],
+                               np.asarray(out_k)[valid], atol=3e-5,
+                               rtol=3e-5)
+    pos, tab = c["np_pos"], np.asarray(c["tables"])
+    fresh = {int(tab[b, p // 16]) for b, p in zip(*np.nonzero(pos >= 0))
+             for p in [pos[b, p]]}
+    keep = [p for p in range(3 * NB) if p not in fresh and p != NB]
+    assert len(fresh) == 3 and len(keep) == 3 * NB - 4
+    for before, want, after in zip((c["kp"], c["vp"]), (kr, vr), pools_k):
+        np.testing.assert_array_equal(np.asarray(after)[keep],
+                                      np.asarray(before)[keep])
+        np.testing.assert_array_equal(np.asarray(after)[sorted(fresh)],
+                                      np.asarray(want)[sorted(fresh)])
+
+
+@pytest.mark.parametrize("window,softcap", [(100, 30.0), (FULL, 20.0)])
+def test_int8_walk_large_query_block(window, softcap):
+    """int8 pages under a window and softcap at 256 query rows, walked in
+    256-row query tiles (one 8-row tile at a decode row) over two
+    1024-key blocks, the window starting inside the first.  The
+    stored scales are amax/127-sized, as ``quantize_rows`` makes them for
+    unit-scale rows: with ``quantize_case``'s unit scales the dequantized
+    keys reach 127 and f32 rounding alone (kernel and reference alike)
+    moves the outputs by more than the tolerance at this depth."""
+    c = quantize_case(make_case(95, S=64, filled=[1000, 10, 0],
+                                ns=[64, 20, 0], Hkv=1, G=4, BS=16, MB=70),
+                      seed=5)
+    c["ks"], c["vs"] = c["ks"] / 127.0, c["vs"] / 127.0
+    small = make_case(95, S=1, filled=[1063], ns=[1], Hkv=1, G=4, BS=16,
+                      MB=70)
+    assert walk_plan(c) == (64, 256) and walk_plan(small) == (64, 8)
+    assert c["live"] > 64
+    assert_parity(c, *run_both_int8(c, window=window, softcap=softcap))
 
 
 # ---------------------------------------------------------------------------

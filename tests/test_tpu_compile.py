@@ -6,7 +6,9 @@ TPU compiler, for a chip that is described and not attached, and assert
 that the kernel lowered to a Mosaic custom call.  Shapes are granite-3-2b's
 (32 query heads, 8 kv heads, head_dim 64) in bf16 with 16-token pages, at
 one chip and at the per-shard width of a 4-way tensor-parallel cluster
-(2 kv heads per shard).
+(2 kv heads per shard); and the benchmark cells' own shapes at their
+deepest walk, where the block size the kernel derives from the shapes
+has to lower and fit VMEM.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test runner imports this file in
@@ -121,3 +123,44 @@ def test_copy_page_compiles(one_chip, hkv, quant):
                     src=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
                     dst=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
         _assert_kernel(lambda **a: K.copy_page_pallas(**a), args)
+
+
+# The benchmark cells' ticks at their deepest walk (136 live pages of 16
+# tokens; the pools flat over the layers, as the layer scan carries them):
+# granite-3-2b's decode tick, 20 kernel rows (16 decoding, 4 padding) of
+# one token at 4 query heads per 64-wide kv head, and glm4-9b's chunk
+# tick, 8 requests of 128-token chunks at 16 query heads per 128-wide kv
+# head (2048 query rows against each kv head).
+CELLS = {"granite_decode": dict(h=32, hkv=8, d=64, rows=20, seg=1,
+                                pages=40 * 2600),
+         "glm4_chunk": dict(h=32, hkv=2, d=128, rows=8, seg=128,
+                            pages=20 * 8400)}
+DEEP = 136
+
+
+@pytest.mark.parametrize("view", ["fused", "unified"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_shapes_compile(one_chip, cell, view):
+    c = CELLS[cell]
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    R, seg = c["rows"], c["seg"]
+    T = R * seg if view == "unified" else R
+    s = 1 if view == "unified" else seg
+    args = dict(q=sds((T, s, c["h"], c["d"])),
+                k_new=sds((T, s, c["hkv"], c["d"])),
+                v_new=sds((T, s, c["hkv"], c["d"])),
+                k_pool=sds((c["pages"], c["hkv"], BS, c["d"])),
+                v_pool=sds((c["pages"], c["hkv"], BS, c["d"])),
+                positions=sds((T, s), jnp.int32),
+                window=sds((), jnp.int32))
+    if view == "fused":
+        args["block_tables"] = sds((R, DEEP), jnp.int32)
+        _assert_kernel(lambda **a: K.paged_attention_update_pallas(
+            **a, softcap=0.0, max_live_blocks=DEEP), args)
+        return
+    args["req_tables"] = sds((R, DEEP), jnp.int32)
+    args["row_map"] = sds((R, seg), jnp.int32)
+    _assert_kernel(lambda **a: paged_ops.paged_attention_unified(
+        **a, softcap=0.0, max_live_blocks=DEEP, max_seg_len=seg,
+        use_pallas=True, interpret=False), args)
