@@ -3,9 +3,11 @@ the whole model step, computed from shapes.
 
 Counts are logical: a head of 64 dims moves 64 dims, whatever lane padding
 the device stores it with, so padding shows as a lower roofline share and
-never as more work.  Every function takes the configuration's ``model``
-block (``n_layers``, ``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``,
-``d_ff``, ``vocab``, ``dtype``).
+never as more work.  The counts take the configuration's ``model`` block
+(``n_layers``, ``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``,
+``d_ff``, ``vocab``, ``dtype``).  ``segments_work``, ``itemsize`` and
+``roofline_seconds`` serve every architecture module; the rest are the
+dense module's (``archs/dense_gqa.py``).
 """
 from __future__ import annotations
 
@@ -85,3 +87,4 @@ def roofline_seconds(flops: float, nbytes: float, peak: dict) -> float:
     """The least time the chip could take: the larger of the two bounds."""
     return max(flops / peak["bf16_flops_per_s"],
                nbytes / peak["hbm_bytes_per_s"])
+

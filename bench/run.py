@@ -7,21 +7,23 @@ A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a model
 configuration (``bench/configs/<config>.json``) under a traffic mix
 (``bench/mixes/<traffic>.json``).  One process, on the chip it starts on:
 
-1. set-up: the weights are made on the device from ``--seed``
-   (``weights.py``), the serving engine is built, every program shape the
-   cell's traffic can reach is compiled or loaded from the persistent
-   cache, and the clients are started (closed loop: each client's first
-   request with a staggered output length, so completions are spread
-   from the start; set-up ends once every first prompt is prefilled);
+1. set-up: the weights are made on the device from ``--seed`` by the
+   configuration's architecture module (``bench/archs/<module>.py``, named
+   by the file's ``bench_arch``, ``dense_gqa`` where it names none), the
+   serving engine is built, every program shape the cell's traffic can
+   reach is compiled or loaded from the persistent cache, and the
+   clients are started (closed loop: each client's first request with a
+   staggered output length, so completions are spread from the start;
+   set-up ends once every first prompt is prefilled);
 2. the window: ``--seconds`` of traffic through the program's
    ``ServingFrontend`` -> ``PagedServingEngine`` (unified tick) ->
    ``paged_attn.unified_step`` -> the Pallas paged kernel, every token
    timed when the front end makes it visible;
 3. the check: a seeded sample of the requests the window finished, the
-   longest among them, is run through the float32 reference
-   (``reference.py``); the widest gap by which a served token's
-   reference logit lies below the reference's best must stay under the
-   cell's limit (``bench/limits/<workload>.json``).
+   longest among them, is run through the module's float32 reference;
+   the widest gap by which a served token's reference logit lies below
+   the reference's best must stay under the cell's limit
+   (``bench/limits/<workload>.json``).
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` records
 a profiler trace of the window and prints the per-layer metrics, each
@@ -40,7 +42,6 @@ import time
 T_PROCESS = time.perf_counter()      # set-up counts from process start
 
 import argparse  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -66,6 +67,7 @@ BLOCK = 16                            # tokens per KV page
 RAMP_TICKS = 10_000                   # set-up prefill ticks, at most
 CHECK_TOKENS = 600                    # served tokens the check compares
 CHECK_REQUESTS = 6                    # ... over at most this many requests
+DEFAULT_ARCH = "dense_gqa"            # a configuration's module, unnamed
 
 
 class BenchError(RuntimeError):
@@ -103,17 +105,35 @@ def load_limits(workload: str, bench: Path = BENCH) -> dict:
     return json.loads(path.read_text())
 
 
+def _load_module(path: Path, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str, bench: Path = BENCH):
     """The per-layer metric reader ``bench/metrics/<name>.py``: a module
     with ``read(run) -> float | None``."""
     path = bench / "metrics" / f"{name}.py"
     if not path.is_file():
         raise BenchError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_module(path, f"bench_metric_{name}")
+
+
+def load_arch(name: str, bench: Path = BENCH):
+    """The architecture module ``bench/archs/<name>.py``: weights,
+    reference, program config, work counts and kernel names of one kind
+    of model (``archs/dense_gqa.py`` lists what it gives)."""
+    path = bench / "archs" / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"no architecture module {name!r} at {path}")
+    return _load_module(path, f"bench_arch_{name}")
+
+
+def arch_for(spec: dict, bench: Path = BENCH):
+    """The module the configuration's file names (``bench_arch``)."""
+    return load_arch(spec.get("bench_arch", DEFAULT_ARCH), bench)
 
 
 def cell_metrics(manifest: dict, cell: dict, key: str) -> List[dict]:
@@ -149,18 +169,6 @@ class CompileCounter:
     def _dur(self, event, secs, **_):
         if event == "/jax/core/compile/backend_compile_duration":
             self.n += 1
-
-
-def program_config(spec: dict):
-    """The program's ModelConfig for the configuration file."""
-    from repro.config import get_config
-    model = spec["model"]
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-            "d_ff", "vocab", "act", "norm", "rope_theta", "rope_fraction",
-            "tie_embeddings", "dtype")
-    cfg = dataclasses.replace(get_config(spec["arch"]),
-                              **{k: model[k] for k in keys})
-    return cfg
 
 
 def engine_settings(spec: dict, mix: dict) -> dict:
@@ -446,12 +454,12 @@ def check_sample(harness: Harness, t_close: float, seed: int) -> List[int]:
     return pick
 
 
-def run_check(model: dict, params, harness: Harness, pick: List[int],
+def run_check(arch, model: dict, params, harness: Harness, pick: List[int],
               pad_to: int, low: Optional[str] = None) -> Dict[str, float]:
-    """The widest gap over the sampled requests; with ``low`` also the
-    control's (``control_gap``): the gap of the tokens a forward in that
-    lower precision puts first, on the same prompts and served tokens."""
-    from bench import reference
+    """The widest gap over the sampled requests, by the architecture
+    module's reference; with ``low`` also the control's (``control_gap``):
+    the gap of the tokens a forward in that lower precision puts first,
+    on the same prompts and served tokens."""
     fe = harness.fe
     out = {"widest_gap": 0.0, "served_tokens": 0, "short_requests": 0,
            "requests": len(pick)}
@@ -461,8 +469,8 @@ def run_check(model: dict, params, harness: Harness, pick: List[int],
         fr = fe._reqs[fid]
         if fr.done and len(fr.tokens) != fr.max_new_tokens:
             out["short_requests"] += 1
-        g = reference.served_gaps(model, params, fr.prompt, fr.tokens,
-                                  low=low, pad_to=pad_to)
+        g = arch.served_gaps(model, params, fr.prompt, fr.tokens,
+                             low=low, pad_to=pad_to)
         if low is not None:
             g, c = g
             out["control_gap"] = max(out["control_gap"], float(c.max()))
@@ -479,10 +487,12 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
              mix: Optional[dict] = None, limits: Optional[dict] = None,
              t_process: float = T_PROCESS, fault=None,
              control: Optional[str] = None,
-             peak: Optional[dict] = None) -> dict:
+             peak: Optional[dict] = None, bench: Path = BENCH) -> dict:
     """One run of ``cell``; returns the result line as a dict.
 
-    ``spec``/``mix``/``limits`` default to the files the manifest names.
+    ``spec``/``mix``/``limits`` default to the files the manifest names,
+    found under ``bench`` with the architecture module, the metric
+    readers and the peaks.
     ``fault`` (tests only) is called with the engine before the window to
     break the timed path; ``control`` (a float8 type)
     replaces the served tokens by those a reference forward in that
@@ -491,11 +501,11 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
     import jax
     from repro.serving.engine import PagedServingEngine
 
-    from bench import weights
-    spec = spec or load_config(cell["config"])
-    mix = mix or traffic.load_mix(cell["traffic"])
-    limits = limits or load_limits(cell["name"])
+    spec = spec or load_config(cell["config"], bench)
+    mix = mix or traffic.load_mix(cell["traffic"], bench / "mixes")
+    limits = limits or load_limits(cell["name"], bench)
     model = spec["model"]
+    arch = arch_for(spec, bench)
     dev = jax.devices()[0]
     counter = CompileCounter()
 
@@ -507,8 +517,8 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
         phases[name] = now - mark[0]
         mark[0] = now
 
-    cfg = program_config(spec)
-    params = weights.make_params(model, seed)
+    cfg = arch.program_config(spec)
+    params = arch.make_params(model, seed)
     jax.block_until_ready(params)
     phase("start_and_weights_s")
     es = engine_settings(spec, mix)
@@ -561,7 +571,7 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
     del engine
     gc.collect()
     mark[0] = time.perf_counter()
-    chk = run_check(model, params, harness, pick,
+    chk = run_check(arch, model, params, harness, pick,
                     es_used["max_blocks_per_seq"] * BLOCK, low=control)
     del params
     gc.collect()
@@ -569,7 +579,7 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
 
     if trace:
         from bench import trace_reduce
-        reduced = trace_reduce.reduce_dir(trace_dir)
+        reduced = trace_reduce.reduce_dir(trace_dir, arch.KERNELS)
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     lim = limits["widest_gap"]
@@ -591,9 +601,9 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
                and chk["short_requests"] == 0 and failed == 0)
 
     if peak is None and trace:
-        peak = win.peak_for(dev.device_kind, BENCH)
+        peak = win.peak_for(dev.device_kind, bench)
     run = win.RunData(
-        model=model, mix=mix, engine=es_used, t_open=t_open,
+        model=model, arch=arch, mix=mix, engine=es_used, t_open=t_open,
         t_close=t_close, ticks=harness.ticks, telemetry_ticks=tel_ticks,
         telemetry_spans=tel_spans, telemetry_epoch=tel_epoch,
         window=w, reached_t=harness.reached_t, submit_t=harness.submit_t,
@@ -610,7 +620,7 @@ def run_cell(manifest: dict, cell: dict, seed: int, seconds: float,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     else:
         for m in cell_metrics(manifest, cell, "per_layer"):
-            v = load_reader(m["name"]).read(run)
+            v = load_reader(m["name"], bench).read(run)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,

@@ -20,7 +20,7 @@ def test_reference_matches_model_forward(frac, tied):
     spec = run.load_config("tiny", DATA)
     spec["model"].update(rope_fraction=frac, tie_embeddings=tied)
     model = spec["model"]
-    cfg = run.program_config(spec)
+    cfg = run.arch_for(spec).program_config(spec)
     params = weights.make_params(model, 2 ** 34 + 3)
     toks = np.random.default_rng(0).integers(0, model["vocab"], 40)
     with jax.default_matmul_precision("highest"):
@@ -35,7 +35,7 @@ def test_reference_matches_model_forward(frac, tied):
 def test_weights_match_the_engines_layout():
     from repro.serving.paged_attn import init_serving_params
     spec = run.load_config("tiny", DATA)
-    cfg = run.program_config(spec)
+    cfg = run.arch_for(spec).program_config(spec)
     ours = jax.eval_shape(lambda: weights.make_params(spec["model"], 1))
     theirs = jax.eval_shape(lambda k: init_serving_params(cfg, k),
                             jax.random.PRNGKey(0))

@@ -14,6 +14,7 @@ from bench import run, trace_reduce, traffic
 from bench import window as win
 
 DATA = run.BENCH / "tests" / "data"
+KERNELS = run.load_arch("dense_gqa").KERNELS
 
 
 def _reader(name):
@@ -22,10 +23,10 @@ def _reader(name):
 
 def _run_data(ticks):
     return win.RunData(
-        model={}, mix={}, engine={}, t_open=10.0, t_close=20.0, ticks=[],
-        telemetry_ticks=ticks, telemetry_spans=[], telemetry_epoch=10.0,
-        window={}, reached_t={}, submit_t={}, compiles_in_window=0,
-        trace=None, peak=None)
+        model={}, arch=None, mix={}, engine={}, t_open=10.0, t_close=20.0,
+        ticks=[], telemetry_ticks=ticks, telemetry_spans=[],
+        telemetry_epoch=10.0, window={}, reached_t={}, submit_t={},
+        compiles_in_window=0, trace=None, peak=None)
 
 
 def _tick(i, t, dispatch_t, ready_t, **phase_s):
@@ -83,8 +84,9 @@ def _ev(name, start, dur):
 
 
 def test_serve_spans_leave_the_reduction_alone():
-    """Device numbers read no host span; gaps are still named by the
-    harness's ``bench.*`` spans."""
+    """Device numbers read no host span; a gap is named by the innermost
+    span over its middle, the program's ``serve.*`` phase where one
+    covers it, else the harness's ``bench.*`` span."""
     ms = 1_000_000
     device = NS(name="/device:TPU:0", lines=[NS(name="XLA Ops", events=[
         _ev("paged_attention_update_pallas.5", 10 * ms, 20 * ms),
@@ -95,14 +97,20 @@ def test_serve_spans_leave_the_reduction_alone():
             _ev("bench.window", 0, 100 * ms),
             _ev("bench.step_end", 30 * ms, 25 * ms), *serve])])
 
-    plain = trace_reduce.reduce_planes([host(), device])
+    plain = trace_reduce.reduce_planes([host(), device], KERNELS)
     spans = trace_reduce.reduce_planes([host(
         _ev("serve.sync", 30 * ms, 5 * ms),
         _ev("serve.unpack", 35 * ms, 15 * ms),
-        _ev("serve.pack", 55 * ms, 4 * ms)), device])
-    assert spans == plain
-    assert dict((round(s, 6), n) for n, s in spans["idle_gaps"])[0.03] \
-        == "bench.step_end"
+        _ev("serve.pack", 55 * ms, 4 * ms)), device], KERNELS)
+    labels = lambda r: dict((round(s, 6), n)  # noqa: E731
+                            for n, s in r["idle_gaps"])
+    assert {k: v for k, v in spans.items() if k != "idle_gaps"} == \
+        {k: v for k, v in plain.items() if k != "idle_gaps"}
+    assert labels(plain)[0.03] == "bench.step_end"
+    # the gap [30, 60) has its middle (45) in serve.unpack
+    assert labels(spans)[0.03] == "serve.unpack"
+    assert [s for _, s in spans["idle_gaps"]] == \
+        [s for _, s in plain["idle_gaps"]]
 
 
 def _recorded(name):
@@ -115,7 +123,8 @@ def _recorded(name):
 
 
 def test_recorded_glm4_excerpt_reads_as_before():
-    r = trace_reduce.reduce_planes(_recorded("glm4_ticks.trace.json.gz"))
+    r = trace_reduce.reduce_planes(_recorded("glm4_ticks.trace.json.gz"),
+                                   KERNELS)
     assert r["busy_s"] == pytest.approx(0.249264691, abs=1e-12)
     assert r["window_s"] == pytest.approx(10.179665415, abs=1e-12)
     assert r["kernel_s"] == pytest.approx({"paged_attention": 0.219313706},
@@ -141,7 +150,7 @@ def test_recorded_granite_excerpt_has_the_program_spans():
     assert len(calls) == 2 * 40
     assert {trace_reduce.op_name(e.name).rsplit(".", 1)[0]
             for e in calls} == {"paged_attention_update_pallas"}
-    r = trace_reduce.reduce_planes(planes)
+    r = trace_reduce.reduce_planes(planes, KERNELS)
     assert r["kernel_s"]["paged_attention"] == pytest.approx(
         sum(e.duration_ns for e in calls) * 1e-9)
 
@@ -161,3 +170,6 @@ def test_recorded_granite_excerpt_has_the_program_spans():
             "serve.admit", "serve.pack"} <= set(under)
     mid = (lo + hi) / 2
     assert any(a <= mid <= b for a, b, _ in serve)
+    # and the reduction names that gap by the phase over its middle
+    gaps = dict((round(s * 1e9), n) for n, s in r["idle_gaps"])
+    assert gaps[round(hi - lo)] == "serve.sync"

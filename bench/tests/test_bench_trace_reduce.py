@@ -8,7 +8,9 @@ from types import SimpleNamespace as NS
 
 import pytest
 
-from bench import trace_reduce
+from bench import run, trace_reduce
+
+KERNELS = run.load_arch("dense_gqa").KERNELS
 
 
 def _ev(name, start, dur, stats=()):
@@ -38,7 +40,7 @@ def _planes():
 
 
 def test_busy_is_the_union_inside_the_window():
-    r = trace_reduce.reduce_planes(_planes())
+    r = trace_reduce.reduce_planes(_planes(), KERNELS)
     assert r["window_s"] == pytest.approx(0.1)
     # [10, 40) and [70, 80): 40 ms; the op at 200 ms is outside
     assert r["busy_s"] == pytest.approx(0.040)
@@ -48,7 +50,7 @@ def test_busy_is_the_union_inside_the_window():
 
 
 def test_idle_gaps_are_labelled_by_the_innermost_host_span():
-    r = trace_reduce.reduce_planes(_planes())
+    r = trace_reduce.reduce_planes(_planes(), KERNELS)
     gaps = dict((round(s, 6), n) for n, s in r["idle_gaps"])
     # [40, 70) has its middle (55) in bench.step_begin, not in pump
     assert gaps[0.03] == "bench.step_begin"
@@ -57,9 +59,36 @@ def test_idle_gaps_are_labelled_by_the_innermost_host_span():
     assert sum(s for _, s in r["idle_gaps"]) == pytest.approx(0.06)
 
 
+def test_kernels_are_the_table_given():
+    """The running configuration's module names its kernels: the same
+    planes read another table's kernel, or none."""
+    ms = 1_000_000
+    r = trace_reduce.reduce_planes(_planes(), {"mlp": ("fusion",)})
+    # fusion.1 and fusion.2; fusion.75 sits inside the kernel's wrapper
+    # but is a fusion of its own name, so it counts too
+    assert r["kernel_s"] == pytest.approx({"mlp": 0.030})
+    assert trace_reduce.reduce_planes(_planes(), {})["kernel_s"] == {}
+    assert r["busy_s"] == trace_reduce.reduce_planes(
+        _planes(), KERNELS)["busy_s"] == pytest.approx(40 * ms * 1e-9)
+
+
+def test_idle_gaps_are_named_by_the_programs_phases():
+    ms = 1_000_000
+    planes = _planes()
+    planes[0].lines.append(NS(name="python-2", events=[
+        _ev("serve.sync", 41 * ms, 20 * ms),
+        _ev("serve.gc", 80 * ms, 15 * ms)]))
+    gaps = dict((round(s, 6), n) for n, s in
+                trace_reduce.reduce_planes(planes, KERNELS)["idle_gaps"])
+    # [40, 70): middle 55 in serve.sync, inside bench.step_begin
+    assert gaps[0.03] == "serve.sync"
+    # [80, 100): middle 90 in serve.gc; [0, 10) under no span
+    assert gaps[0.02] == "serve.gc" and gaps[0.01] == "host:other"
+
+
 def test_no_device_plane_reads_nothing():
     host = NS(name="/host:CPU", lines=[])
-    r = trace_reduce.reduce_planes([host])
+    r = trace_reduce.reduce_planes([host], KERNELS)
     assert r["busy_s"] == 0.0 and r["kernel_s"] == {}
 
 
@@ -90,9 +119,11 @@ def test_recorded_trace_kernel_is_its_custom_call_alone():
     ops = _ops(planes)
     calls = [e for e in ops if " custom-call(" in e.name]
     assert len(calls) == 2 * 20 and all(
-        trace_reduce.kernel_of(e.name) == "paged_attention" for e in calls)
-    assert sum(1 for e in ops if trace_reduce.kernel_of(e.name)) == 40
-    r = trace_reduce.reduce_planes(planes)
+        trace_reduce.kernel_of(e.name, KERNELS) == "paged_attention"
+        for e in calls)
+    assert sum(1 for e in ops if trace_reduce.kernel_of(e.name, KERNELS)) \
+        == 40
+    r = trace_reduce.reduce_planes(planes, KERNELS)
     assert r["kernel_s"]["paged_attention"] == pytest.approx(
         sum(e.duration_ns for e in calls) * 1e-9)
 
@@ -100,7 +131,7 @@ def test_recorded_trace_kernel_is_its_custom_call_alone():
 def test_recorded_trace_ops_count_their_own_time():
     planes = _recorded()
     ops = _ops(planes)
-    r = trace_reduce.reduce_planes(planes)
+    r = trace_reduce.reduce_planes(planes, KERNELS)
     loops = [e for e in ops if trace_reduce.op_name(e.name).startswith(
         "while.")]
     assert loops
@@ -109,7 +140,7 @@ def test_recorded_trace_ops_count_their_own_time():
     assert sum(e.duration_ns for e in ops) * 1e-9 > 1.5 * r["busy_s"]
     assert sum(s for _, s in r["top_ops"]) <= r["busy_s"] * (1 + 1e-9)
     top, secs = r["top_ops"][0]
-    assert trace_reduce.kernel_of(top) == "paged_attention"
+    assert trace_reduce.kernel_of(top, KERNELS) == "paged_attention"
     # the kernel encloses no op: its own time is its whole time
     assert secs == pytest.approx(sum(
         e.duration_ns for e in ops

@@ -5,23 +5,26 @@ and nothing else.  Device planes (``/device:TPU:N``) carry one event per
 operation on the ``XLA Ops`` line, named by the instruction's HLO text
 (``%paged_attention_update_pallas.5 = (bf16[...]) custom-call(...)``);
 a loop's event (``%while.2``) encloses the events of its body.  The host
-plane carries the harness's own ``bench.*`` spans
-(``jax.profiler.TraceAnnotation``), among them ``bench.window``, which
-bounds the measured window on the trace's clock.
+plane carries the harness's own ``bench.*`` spans and the program's
+``serve.*`` phases (``jax.profiler.TraceAnnotation``), among them
+``bench.window``, which bounds the measured window on the trace's clock.
 
 Reduced here, within the window:
 
 - busy time: the union of a device's op intervals, averaged over the
   devices that ran anything;
 - kernel time: the summed durations of each kernel's own events: the
-  Pallas custom call, whose instruction is named after the kernel's
-  jitted function (``paged_attention_update_pallas.5``); the XLA fusions
-  and copies around it (the lane-padded pool copy) have names of their
-  own and are not the kernel;
+  Pallas custom call, whose instruction is named after the kernel
+  (``paged_attention_update_pallas.5``); the XLA fusions and copies
+  around it (the lane-padded pool copy) have names of their own and are
+  not the kernel.  Which kernels, and their names, come from the running
+  configuration's architecture module (its ``KERNELS``: a kernel's name
+  -> the HLO names of its custom calls, each op's name being the
+  instruction's with a ``.N`` suffix);
 - the operations that took most time, each by its own time: a loop's
   event less the events inside it;
 - idle gaps: stretches with no op on the device, each labelled by the
-  innermost ``bench.*`` host span that covers its middle.
+  innermost ``bench.*`` or ``serve.*`` host span that covers its middle.
 """
 from __future__ import annotations
 
@@ -32,12 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
 OPS_LINE = "XLA Ops"
-# kernels the benchmark reports a roofline for: name -> the HLO names of
-# the Pallas custom calls that are the kernel (an op's name is the
-# instruction's, with a ``.N`` suffix)
-KERNELS = {"paged_attention": ("paged_attention_update_pallas",
-                               "paged_attention_pallas")}
-
+HOST_SPANS = ("bench.", "serve.")     # the harness's spans, the program's
 
 SHOWN = 160          # characters of an op's HLO text in the breakdown
 
@@ -79,22 +77,23 @@ def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return out
 
 
-def kernel_of(text: str) -> Optional[str]:
-    """The kernel whose custom call the device op ``text`` is (an event's
-    HLO text or its instruction name)."""
+def kernel_of(text: str, kernels: Dict[str, tuple]) -> Optional[str]:
+    """The kernel of ``kernels`` whose custom call the device op ``text``
+    is (an event's HLO text or its instruction name)."""
     name = op_name(text)
     base = name.rsplit(".", 1)
     base = base[0] if len(base) == 2 and base[1].isdigit() else name
-    for kernel, names in KERNELS.items():
+    for kernel, names in kernels.items():
         if base in names:
             return kernel
     return None
 
 
-def reduce_planes(planes) -> dict:
+def reduce_planes(planes, kernels: Dict[str, tuple]) -> dict:
     """``planes``: iterable of objects with ``name`` and ``lines`` (each
     line with ``name`` and ``events`` of ``name``, ``start_ns``,
-    ``duration_ns`` and ``stats``) — a ``ProfileData``'s planes."""
+    ``duration_ns`` and ``stats``) — a ``ProfileData``'s planes;
+    ``kernels``: the architecture module's ``KERNELS``."""
     host_spans: List[Tuple[str, float, float]] = []
     device_ops: Dict[str, List[Tuple[str, float, float]]] = {}
     for plane in planes:
@@ -108,7 +107,7 @@ def reduce_planes(planes) -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in _events(line):
-                    if ev[0].startswith("bench."):
+                    if ev[0].startswith(HOST_SPANS):
                         host_spans.append(ev)
     wins = [s for s in host_spans if s[0] == WINDOW_SPAN]
     if wins:
@@ -132,7 +131,7 @@ def reduce_planes(planes) -> dict:
         busy.append(sum(b - a for a, b in merged) * 1e-9)
         for (n, a, b), own in zip(inside, _self_times(inside)):
             per_op[n[:SHOWN]] += own * 1e-9
-            k = kernel_of(n)
+            k = kernel_of(n, kernels)
             if k:
                 kernel_s[k] += (b - a) * 1e-9
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
@@ -168,11 +167,11 @@ def find_trace(trace_dir: str) -> Optional[str]:
     return max(files, key=os.path.getmtime) if files else None
 
 
-def reduce_file(path: str) -> dict:
+def reduce_file(path: str, kernels: Dict[str, tuple]) -> dict:
     from jax.profiler import ProfileData
-    return reduce_planes(ProfileData.from_file(path).planes)
+    return reduce_planes(ProfileData.from_file(path).planes, kernels)
 
 
-def reduce_dir(trace_dir: str) -> Optional[dict]:
+def reduce_dir(trace_dir: str, kernels: Dict[str, tuple]) -> Optional[dict]:
     path = find_trace(trace_dir)
-    return reduce_file(path) if path else None
+    return reduce_file(path, kernels) if path else None

@@ -12,9 +12,11 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
+
+from bench.flops import roofline_seconds
 
 
 def percentile(values: Sequence[float], p: float) -> Optional[float]:
@@ -61,6 +63,7 @@ def peak_for(kind: str, bench: Path) -> dict:
 class RunData:
     """What a per-layer metric reader can read about one run."""
     model: dict
+    arch: Any                         # the configuration's module
     mix: dict
     engine: dict
     t_open: float
@@ -87,3 +90,18 @@ class RunData:
         a = self.t_open - self.telemetry_epoch
         b = self.t_close - self.telemetry_epoch
         return [e for e in self.telemetry_ticks if a <= e["t"] <= b]
+
+    def roofline_share(self, kernel: str) -> Optional[float]:
+        """Percent of its roofline ``kernel`` reaches in a traced run: the
+        least time of the (FLOPs, bytes) the architecture module's
+        ``kernel_cost`` gives for each of the window's ticks, over the
+        summed duration of the kernel's own events; None where the trace
+        has none."""
+        tr = self.trace
+        if not tr or not tr["kernel_s"].get(kernel):
+            return None
+        least = 0.0
+        for t in self.window_ticks():
+            fl, nb = self.arch.kernel_cost(self.model, t["segments"])[kernel]
+            least += roofline_seconds(fl, nb, self.peak)
+        return 100.0 * least / tr["kernel_s"][kernel]
